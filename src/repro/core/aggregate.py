@@ -128,9 +128,12 @@ def aggregate_scv_tiles(
     """SCV aggregation over the device tile layout.
 
     backend:
-      * "jnp"     — vectorized jnp reference (runs anywhere, used as oracle)
-      * "pallas"  — the TPU kernel (interpret=True on CPU)
-      * "auto"    — pallas on TPU, jnp elsewhere
+      * "jnp"              — vectorized jnp reference (runs anywhere, oracle)
+      * "pallas"           — the compiled TPU kernel; never interpreted, so
+                             lowering fails loudly off a TPU
+      * "pallas_interpret" — the same kernel in Pallas interpret mode (CPU
+                             tests)
+      * "auto"             — pallas on TPU, jnp elsewhere
     """
     from repro.kernels.scv_spmm import ops as scv_ops  # local import: keep core light
     from repro.kernels.scv_spmm import ref as scv_ref
@@ -150,7 +153,7 @@ def aggregate_scv_tiles(
             z, tile=t.tile, n_rows=t.padded_shape[0],
             nnz_in_tile=arr.get("nnz_in_tile"),
             feature_block=feature_block,
-            interpret=(backend == "pallas_interpret" or jax.default_backend() != "tpu"),
+            interpret=(backend == "pallas_interpret"),
         )
     else:
         raise ValueError(f"unknown backend {backend!r}")
@@ -194,7 +197,7 @@ def aggregate_scv_plan(
     elif backend in ("pallas", "pallas_interpret"):
         out = scv_ops.scv_spmm_plan(
             p, z, feature_block=feature_block,
-            interpret=(backend == "pallas_interpret" or jax.default_backend() != "tpu"),
+            interpret=(backend == "pallas_interpret"),
         )
     else:
         raise ValueError(f"unknown backend {backend!r}")

@@ -49,13 +49,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# jax >= 0.6 re-homes shard_map to jax.*; the installed 0.4.x only has the
-# experimental location, so the first branch is forward-compat, not live.
-try:
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.partition import nnz_imbalance, shard_plan, split_equal_nnz
 from repro.core.scv import SCVBucketedPlan, SCVPlan
@@ -431,8 +426,7 @@ def aggregate_sharded(
             local_plan = segs[0] if len(segs) == 1 else SCVBucketedPlan(segs)
             out = scv_ops.scv_spmm_plan(
                 local_plan, z_local, feature_block=feature_block,
-                interpret=(backend == "pallas_interpret"
-                           or jax.default_backend() != "tpu"),
+                interpret=(backend == "pallas_interpret"),
                 init="zeros",
             )
         if tp == 1:
@@ -445,11 +439,11 @@ def aggregate_sharded(
         mesh=sp.mesh,
         in_specs=(specs, P(None, FEATURE_AXIS)),
         out_specs=P(None, FEATURE_AXIS),  # psum leaves "tiles" replicated
-        # pallas_call has no replication rule (jax 0.4.x): skip the static
+        # pallas_call has no varying-manual-axes rule: skip the static
         # check there — the psum above makes the output replicated either
         # way; the jnp path keeps the check as a safety net (not at
         # tp == 1, where the psum is skipped and the axis is trivial)
-        check_rep=(backend == "jnp" and tp > 1),
+        check_vma=(backend == "jnp" and tp > 1),
     )
     return fn(sp, z)[: sp.shape[0], :f]
 
@@ -574,8 +568,15 @@ class PlanExecutor:
                 tile_col=jnp.take_along_axis(seg.tile_col, src, axis=1),
             )
 
+        # place each span on its mesh row (replicated along the feature
+        # axis): the spans live where the shard_map body reads them, not
+        # on the default device awaiting a transfer every call
+        span_sharding = NamedSharding(mesh, P(TILE_AXIS))
         return ShardedPlan(
-            segments=tuple(dev(s, p) for s, p in zip(segs, parts)),
+            segments=tuple(
+                jax.device_put(dev(s, p), span_sharding)
+                for s, p in zip(segs, parts)
+            ),
             mesh=mesh,
             decision=decision,
         )

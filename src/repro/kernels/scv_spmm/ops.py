@@ -23,6 +23,32 @@ from repro.kernels.scv_spmm import ref as _ref
 from repro.kernels.scv_spmm.scv_spmm import scv_spmm_pallas
 
 
+#: Most tiles one kernel launch takes.  The three prefetched i32 tile
+#: arrays (tile_row, tile_col, nnz_in_tile) live in SMEM, 1 MiB on v5e,
+#: and 65,536 tiles fill 768 KiB of it.  A longer tile sequence runs as a
+#: chain of accumulate-mode launches over consecutive spans.
+MAX_LAUNCH_TILES = 1 << 16
+
+
+def n_launches(n_tiles: int) -> int:
+    """Kernel launches a tile sequence of ``n_tiles`` takes."""
+    return -(-int(n_tiles) // MAX_LAUNCH_TILES)
+
+
+def _chain(arrays, z, out, statics):
+    """Launch the kernel over ``arrays`` (tile_row, tile_col, nnz_in_tile,
+    rows, cols, vals) in spans of at most ``MAX_LAUNCH_TILES`` tiles.
+    Each launch after the first, and every launch when ``out`` is given,
+    runs in accumulate mode on the running output."""
+    for lo in range(0, arrays[0].shape[0], MAX_LAUNCH_TILES):
+        span = tuple(a[lo:lo + MAX_LAUNCH_TILES] for a in arrays)
+        if out is None:
+            out = _spmm(*span, z, *statics)
+        else:
+            out = _spmm_acc(*span, z, out, *statics)
+    return out
+
+
 def ensure_row_coverage(
     tile_row: np.ndarray,
     tile_col: np.ndarray,
@@ -212,7 +238,8 @@ def scv_spmm(
     """out = Â Z over the SCV tile layout.  Returns f32[n_rows, F]."""
     from repro.core.scv import DEFAULT_CHUNK
 
-    if tile_row.shape[0] == 0:
+    nt = tile_row.shape[0]
+    if nt == 0:
         return jnp.zeros((n_rows, z.shape[1]), jnp.float32)
     f_orig = z.shape[1]
     feature_block = _feature_block_for(f_orig, feature_block)
@@ -222,23 +249,24 @@ def scv_spmm(
         # would be nonzero on padding slots (they share local (0, 0) with a
         # real corner entry, and <g[0], z[0]> is generally nonzero)
         nnz_in_tile = _infer_nnz(rows, cols, vals)
-    out = _spmm(
+    arrays = (
         tile_row.astype(jnp.int32),
         tile_col.astype(jnp.int32),
         nnz_in_tile.astype(jnp.int32),
         rows.astype(jnp.int32),
         cols.astype(jnp.int32),
         vals,
-        zp,
-        tile,
-        n_rows,
-        feature_block,
-        interpret,
-        body,
-        int(DEFAULT_CHUNK if chunk is None else chunk),
-        dense_threshold,
     )
-    return out[:, :f_orig]
+    statics = (
+        tile, n_rows, feature_block, interpret, body,
+        int(DEFAULT_CHUNK if chunk is None else chunk), dense_threshold,
+    )
+    # one launch zero-initializes only the strips its tiles visit: a tile
+    # sequence split over several launches chains from explicit zeros
+    out = None
+    if nt > MAX_LAUNCH_TILES:
+        out = jnp.zeros((n_rows, zp.shape[1]), jnp.float32)
+    return _chain(arrays, zp, out, statics)[:, :f_orig]
 
 
 def scv_spmm_plan(
@@ -270,6 +298,11 @@ def scv_spmm_plan(
     **once** for all segments (same tile, same feature_block — per-launch
     re-padding would be redundant work in eager mode).
 
+    A segment longer than ``MAX_LAUNCH_TILES`` tiles (its prefetched tile
+    arrays would overflow SMEM) runs as consecutive spans, one accumulate-
+    mode launch each; the chain then starts from zeros, since no single
+    launch visits every strip.
+
     ``init="zeros"`` starts the chain from an explicit zero accumulator
     instead: every row is then defined even when *no* segment covers it —
     the executor's sharded spans (which carry no per-span coverage) use
@@ -292,27 +325,23 @@ def scv_spmm_plan(
     n_rows = segments[0].padded_shape[0]
     chunk = int(DEFAULT_CHUNK if chunk is None else chunk)
     out = None
-    if init == "zeros":
+    # the coverage launch is the first segment's, as one launch: an empty
+    # first segment, or one split over several launches (MAX_LAUNCH_TILES),
+    # no longer defines every strip, so the chain starts from zeros
+    nt0 = segments[0].tile_row.shape[0]
+    if init == "zeros" or not 0 < nt0 <= MAX_LAUNCH_TILES:
         out = jnp.zeros((n_rows, zp.shape[1]), jnp.float32)
-    for seg in segments:
-        args = (
+    for seg in segments:  # an empty segment launches nothing
+        arrays = (
             seg.tile_row.astype(jnp.int32),
             seg.tile_col.astype(jnp.int32),
             seg.nnz_in_tile.astype(jnp.int32),
             seg.rows.astype(jnp.int32),
             seg.cols.astype(jnp.int32),
             seg.vals,
-            zp,
         )
         statics = (seg.tile, n_rows, fb, interpret, body, chunk, dense_threshold)
-        if seg.tile_row.shape[0] == 0:  # empty segment: nothing to launch
-            if out is None:
-                out = jnp.zeros((n_rows, zp.shape[1]), jnp.float32)
-            continue
-        if out is None:
-            out = _spmm(*args, *statics)
-        else:
-            out = _spmm_acc(*args, out, *statics)
+        out = _chain(arrays, zp, out, statics)
     return out[:, :f_orig]
 
 

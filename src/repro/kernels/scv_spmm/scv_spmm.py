@@ -53,6 +53,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.scv import DEFAULT_CHUNK, dense_tile_threshold
 
+#: Precision of the kernel's one-hot gather/scatter matmuls.  The
+#: "one-hot matmul == exact gather" contract (DESIGN.md §2) needs Z rows
+#: and entry values kept at f32, not rounded to bf16 as XLA's default f32
+#: matmul does on the TPU.  Mosaic's default contracts f32 at f32 on v5e
+#: (same error and time as HIGHEST there); HIGHEST states the contract
+#: instead of leaning on that default.
+ONEHOT_PRECISION = jax.lax.Precision.HIGHEST
+
 
 def _kernel_scalar(
     # scalar-prefetch operands
@@ -93,8 +101,8 @@ def _kernel_scalar(
         out_ref[pl.ds(r, 1), :] += v * zrow
         return 0
 
-    # No `unroll=`: jax (0.4.x and current) raises ValueError for
-    # unrolled fori_loop with traced bounds, and nnz is prefetched data.
+    # No `unroll=`: jax raises ValueError for an unrolled fori_loop with
+    # traced bounds, and nnz is prefetched data.
     jax.lax.fori_loop(0, nnz, body, 0)
 
 
@@ -102,9 +110,9 @@ def _kernel_vector(
     tile_row_ref,  # i32[nt]
     tile_col_ref,  # i32[nt]  (steers z BlockSpec; unused in body)
     nnz_ref,  # i32[nt]
-    rows_ref,  # i32[1, cap]   (VMEM) local row of each entry
-    cols_ref,  # i32[1, cap]   (VMEM) local col of each entry
-    vals_ref,  # f32[1, cap]   (VMEM) value of each entry
+    rows_ref,  # i32[cap // C, C]   (VMEM) local row of each entry
+    cols_ref,  # i32[cap // C, C]   (VMEM) local col of each entry
+    vals_ref,  # f32[cap // C, C]   (VMEM) value of each entry
     z_ref,  # [T, Fb]       (VMEM) combined-feature block
     *refs,  # (out_ref,) or (acc_ref, out_ref) in accumulate mode
     tile: int,
@@ -133,10 +141,13 @@ def _kernel_vector(
         """Scatter matrix S[t, j] = vals[j]*(rows[j]==t) and gather one-hot
         G[u, j] = (cols[j]==u) for chunk k.  Padding entries have val == 0,
         so their S column is zero and they contribute nothing."""
-        sl = pl.ds(k * C, C)
-        r = rows_ref[:, sl]  # (1, C) broadcasts against the (T, C) iota
-        c = cols_ref[:, sl]
-        v = vals_ref[:, sl].astype(jnp.float32)
+        # chunk k is row k of the entry block: a dynamic *sublane* index,
+        # which Mosaic lowers at any C (a dynamic lane offset k*C is
+        # refused unless it is provably a multiple of 128)
+        sl = pl.ds(k, 1)
+        r = rows_ref[sl, :]  # (1, C) broadcasts against the (T, C) iota
+        c = cols_ref[sl, :]
+        v = vals_ref[sl, :].astype(jnp.float32)
         scatter = jnp.where(iota_tc == r, v, 0.0)
         onehot = (iota_tc == c).astype(jnp.float32)
         return scatter, onehot
@@ -144,7 +155,7 @@ def _kernel_vector(
     # Hybrid rule: a tile dense enough that T^2 MXU MACs beat nnz VPU FMAs
     # is densified in-kernel and runs as one plain matmul.  The branch is
     # compiled out when no tile of this capacity can reach the threshold.
-    use_dense = 0 <= dense_threshold < rows_ref.shape[1]
+    use_dense = 0 <= dense_threshold < rows_ref.shape[0] * C
     is_dense = nnz > dense_threshold if use_dense else False
 
     @pl.when(jnp.logical_and(nnz > 0, jnp.logical_not(is_dense)))
@@ -157,10 +168,12 @@ def _kernel_vector(
             gathered = jax.lax.dot_general(
                 onehot, z, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
+                precision=ONEHOT_PRECISION,
             )
             out_ref[...] += jax.lax.dot_general(
                 scatter, gathered, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
+                precision=ONEHOT_PRECISION,
             )
             return 0
 
@@ -176,6 +189,7 @@ def _kernel_vector(
                 return d + jax.lax.dot_general(
                     scatter, onehot, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32,
+                    precision=ONEHOT_PRECISION,
                 )
 
             d = jax.lax.fori_loop(
@@ -184,6 +198,7 @@ def _kernel_vector(
             out_ref[...] += jax.lax.dot_general(
                 d, z_ref[...].astype(jnp.float32), (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
+                precision=ONEHOT_PRECISION,
             )
 
 
@@ -245,11 +260,21 @@ def scv_spmm_pallas(
         kernel = functools.partial(
             _kernel_vector, tile=T, chunk=C, dense_threshold=thr
         )
-        # entry arrays feed vector compute (iota compares + matmuls): VMEM
-        entry_space = pltpu.VMEM
+        # entry arrays feed vector compute (iota compares + matmuls), so
+        # they live in VMEM as [nt, cap // C, C]: one tile's (cap // C, C)
+        # block spans the array's last two dims (the TPU tiling accepts it
+        # at any C), and chunk k is a sublane row of it
+        rows, cols, vals = (a.reshape(nt, cap // C, C) for a in (rows, cols, vals))
+        entry_spec = pl.BlockSpec(
+            (None, cap // C, C), lambda f, t, tr, tc, nz: (t, 0, 0)
+        )
     elif body == "scalar":
         kernel = _kernel_scalar
-        entry_space = pltpu.SMEM
+        # interpret-mode bench baseline only: a (1, cap) block is below
+        # the TPU tiling minimum, so this body does not lower for the chip
+        entry_spec = pl.BlockSpec(
+            (1, cap), lambda f, t, tr, tc, nz: (t, 0), memory_space=pltpu.SMEM
+        )
     else:
         raise ValueError(f"unknown kernel body {body!r}")
 
@@ -257,15 +282,9 @@ def scv_spmm_pallas(
 
     in_specs = [
         # entry coordinate/value arrays: one tile's slice per step
-        pl.BlockSpec(
-            (1, cap), lambda f, t, tr, tc, nz: (t, 0), memory_space=entry_space
-        ),
-        pl.BlockSpec(
-            (1, cap), lambda f, t, tr, tc, nz: (t, 0), memory_space=entry_space
-        ),
-        pl.BlockSpec(
-            (1, cap), lambda f, t, tr, tc, nz: (t, 0), memory_space=entry_space
-        ),
+        entry_spec,
+        entry_spec,
+        entry_spec,
         # Z block steered by the prefetched tile column
         pl.BlockSpec((T, Fb), lambda f, t, tr, tc, nz: (tc[t], f)),
     ]
@@ -294,4 +313,5 @@ def scv_spmm_pallas(
         out_shape=jax.ShapeDtypeStruct((n_rows, f_p), jnp.float32),
         input_output_aliases=aliases,
         interpret=interpret,
+        name="scv_spmm",
     )(*operands)

@@ -69,6 +69,7 @@ from repro.core.scv import (
     SCVPlan,
 )
 from repro.core.validate import check_coo, validate_plan
+from repro.kernels.scv_spmm.ops import n_launches
 from repro.models.gnn import (
     BatchedGraph,
     GNNConfig,
@@ -317,8 +318,13 @@ def _assemble_segment(
     nt_bucket = 8
     while nt_bucket < nt:
         nt_bucket *= 2
-    # repeat-last-coordinate padding tiles (an empty composite stays empty)
-    n_fill = nt_bucket - nt if nt else 0
+    # repeat-last-coordinate padding tiles.  A later segment that no member
+    # fills still gets the smallest bucket of inert tiles (zero nnz at
+    # block-row 0, which the accumulate-mode launch passes through):
+    # whether a wave's graphs happen to reach a capacity bucket must not
+    # change the composite's leaf shapes, or jit retraces within one
+    # padding bucket.  Only an empty composite stays empty.
+    n_fill = nt_bucket - nt if nt or not first_segment else 0
 
     shift = np.repeat(blk_off[:k], nts)  # per-tile block-diagonal offset
     cov_rows = np.arange(n_aligned // T, pad_nodes // T, dtype=np.int64)[:n_cov]
@@ -474,22 +480,18 @@ def plan_launches(plan) -> int:
 
     A single-cap ``SCVPlan`` is one launch; a bucketed plan chains one
     launch per **non-empty** capacity segment through the aliased
-    accumulator (empty segments are skipped at dispatch — see
-    ``kernels/scv_spmm/ops.scv_spmm_plan``); a sharded plan runs its
+    accumulator (empty segments are skipped at dispatch, and a segment
+    longer than ``ops.MAX_LAUNCH_TILES`` tiles takes one launch per span —
+    see ``kernels/scv_spmm/ops.scv_spmm_plan``); a sharded plan runs its
     per-segment launches on every mesh instance
     (``tile_parts x feature_parts`` shard_map bodies).  The forward then
     multiplies by ``GNNConfig.n_layers`` — that factor is the caller's
     (every model kind aggregates exactly once per layer)."""
     if isinstance(plan, ShardedPlan):
-        per_device = sum(
-            1 for s in plan.segments if int(np.asarray(s.tile_row).size) > 0
-        )
+        per_device = sum(n_launches(s.tile_row.shape[-1]) for s in plan.segments)
         return per_device * plan.decision.n_devices
-    if isinstance(plan, SCVBucketedPlan):
-        return sum(
-            1 for s in plan.segments if int(np.asarray(s.tile_row).size) > 0
-        )
-    return 1 if int(np.asarray(plan.tile_row).size) > 0 else 0
+    segments = getattr(plan, "segments", (plan,))
+    return sum(n_launches(s.tile_row.shape[0]) for s in segments)
 
 
 # ---------------------------------------------------------------------------
@@ -889,12 +891,22 @@ class GraphServeEngine:
         dispatch returns once the work is enqueued, so the scheduler can
         overlap host-side assembly of the next wave (plan-cache lookups,
         composite concatenation) with this wave's device time."""
+        bg, args = self._forward_args(wave)
+        return bg, gnn_forward_jit(*args)
+
+    def lower(self, wave: list[GraphRequest]):
+        """The jitted forward that serving ``wave`` runs, lowered but not
+        run: ``.compile()`` it to time the compile or to read its HLO.
+        The wave's composite plan is built and cached as serving would,
+        and the forward's jit cache keeps the compiled program."""
+        return gnn_forward_jit.lower(*self._forward_args(wave)[1])
+
+    def _forward_args(self, wave: list[GraphRequest]):
+        """The wave's composite and the arguments of its jitted forward."""
         bg = self._batch_plan(wave)
         params, mcfg = self.models[wave[0].model]
-        out = gnn_forward_jit(
-            params, mcfg, bg.graph, batch_features(bg, [r.x for r in wave])
-        )
-        return bg, out
+        x = batch_features(bg, [r.x for r in wave])
+        return bg, (params, mcfg, bg.graph, x)
 
     def _finish_wave(self, wave, bg, out) -> list[GraphRequest]:
         """Materialize a dispatched wave's outputs (blocks on the device),

@@ -13,6 +13,7 @@ to w.  ``ultra``/``highly`` sparse categories follow Fig. 6's split.
 from __future__ import annotations
 
 import dataclasses
+import zlib
 
 import numpy as np
 
@@ -88,6 +89,13 @@ def gcn_normalize(a: COOMatrix) -> COOMatrix:
     return COOMatrix(rows, cols, w, (n, n))
 
 
+def name_seed(name: str) -> int:
+    """Per-dataset seed offset.  A CRC of the name, not ``hash()``: Python
+    salts string hashes per process, so ``hash`` would draw a different
+    graph for the same name on every run."""
+    return zlib.crc32(name.encode()) % 2**16
+
+
 def load(
     name: str,
     max_edges: int = 2_000_000,
@@ -98,7 +106,7 @@ def load(
     scale = min(1.0, max_edges / spec.edges)
     n = max(64, int(spec.nodes * scale))
     m = max(256, int(spec.edges * scale))
-    adj = powerlaw_graph(n, m, seed=seed + hash(name) % 2**16)
+    adj = powerlaw_graph(n, m, seed=seed + name_seed(name))
     if normalize:
         adj = gcn_normalize(adj)
     return GraphData(spec=spec, adj=adj, feature_size=spec.feature_size, scale=scale)

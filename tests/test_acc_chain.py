@@ -101,6 +101,33 @@ def test_init_zeros_matches_and_needs_no_coverage(rng):
         kops.scv_spmm_plan(plan, z, interpret=True, init="sideways")
 
 
+def test_segments_longer_than_one_launch_chain_spans(rng, monkeypatch):
+    """A segment past MAX_LAUNCH_TILES (the SMEM bound on prefetched tile
+    arrays) runs as several accumulate-mode launches from zeros, and the
+    result stays on the oracle for both the plan and the tiles entry."""
+    from repro.serve.graph_engine import plan_launches
+
+    _, _, plan = _bucketed(rng)
+    z = jnp.asarray(rng.integers(-4, 5, (128, 16)).astype(np.float32))
+    oracle = np.asarray(kref.scv_spmm_reference_plan(plan, z))
+    one_launch_each = plan_launches(plan)
+    monkeypatch.setattr(kops, "MAX_LAUNCH_TILES", 4)
+    assert plan_launches(plan) == sum(
+        -(-s.n_tiles // 4) for s in plan.segments
+    ) > one_launch_each
+    out = np.asarray(kops.scv_spmm_plan(plan, z, interpret=True, feature_block=8))
+    np.testing.assert_array_equal(out, oracle)
+    seg = plan.segments[0]
+    single = np.asarray(kops.scv_spmm(
+        seg.tile_row, seg.tile_col, seg.rows, seg.cols, seg.vals, z,
+        tile=seg.tile, n_rows=seg.padded_shape[0],
+        nnz_in_tile=seg.nnz_in_tile, feature_block=8, interpret=True,
+    ))
+    np.testing.assert_array_equal(
+        single, np.asarray(kref.scv_spmm_reference_plan(seg, z))
+    )
+
+
 def test_chain_grads_match_reference(rng):
     _, _, plan = _bucketed(rng)
     z = jnp.asarray(rng.integers(-4, 5, (128, 16)).astype(np.float32))

@@ -97,3 +97,39 @@ def test_dataset_registry_stats():
     # density should be in the ballpark of Table I (self loops added)
     dens = g.adj.nnz / (g.adj.shape[0] ** 2)
     assert dens < 10 * (spec.edges / spec.nodes**2 + 1.0 / spec.nodes)
+
+
+def test_dataset_load_is_the_same_graph_in_every_process():
+    """``load`` seeds from a stable digest of the name: processes with
+    different string-hash salts draw the identical graph."""
+    import hashlib
+    import os
+    import pathlib
+    import subprocess
+    import sys
+
+    from repro.simul import datasets
+    from repro.simul.datasets import name_seed
+
+    src = str(pathlib.Path(datasets.__file__).parents[2])
+
+    assert name_seed("arxiv") == 64760  # pinned: crc32(b"arxiv") % 2**16
+    code = (
+        "import hashlib; from repro.simul.datasets import load; "
+        "a = load('citeseer', max_edges=20_000).adj; "
+        "print(hashlib.sha256(a.rows.tobytes() + a.cols.tobytes()"
+        " + a.vals.tobytes()).hexdigest())"
+    )
+    digests = set()
+    for salt in ("0", "12345"):
+        env = dict(os.environ, PYTHONHASHSEED=salt, PYTHONPATH=src)
+        out = subprocess.run(
+            [sys.executable, "-c", code], env=env, check=True,
+            capture_output=True, text=True,
+        )
+        digests.add(out.stdout.strip())
+    a = load("citeseer", max_edges=20_000).adj
+    digests.add(hashlib.sha256(
+        a.rows.tobytes() + a.cols.tobytes() + a.vals.tobytes()
+    ).hexdigest())
+    assert len(digests) == 1
