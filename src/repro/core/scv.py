@@ -45,6 +45,7 @@ import numpy as np
 
 from repro.core import morton
 from repro.core.formats import COOMatrix
+from repro.spans import span
 
 ROW_MAJOR = "row_major"
 ZMORTON = "zmorton"
@@ -631,7 +632,7 @@ def plan_from_tiles(
         tr, tc, rs, cs, vs, nz = ensure_row_coverage(
             tr, tc, rs, cs, vs, nz, t.padded_shape[0] // t.tile
         )
-    perm = None
+    pp = None
     if with_perm and t.perm is not None:
         if t.nnz >= 2**31:  # device perm is i32; refuse to wrap silently
             raise ValueError(
@@ -639,20 +640,20 @@ def plan_from_tiles(
             )
         pp = np.full((len(tr), t.cap), -1, np.int32)
         pp[: t.perm.shape[0]] = t.perm.astype(np.int32)
-        perm = jnp.asarray(pp)
-    return SCVPlan(
-        tile_row=jnp.asarray(tr),
-        tile_col=jnp.asarray(tc),
-        rows=jnp.asarray(rs),
-        cols=jnp.asarray(cs),
-        vals=jnp.asarray(vs),
-        nnz_in_tile=jnp.asarray(nz),
-        perm=perm,
-        tile=t.tile,
-        cap=t.cap,
-        shape=t.shape,
-        order=t.order,
-    )
+    with span("serve.plan.to_device"):
+        return SCVPlan(
+            tile_row=jnp.asarray(tr),
+            tile_col=jnp.asarray(tc),
+            rows=jnp.asarray(rs),
+            cols=jnp.asarray(cs),
+            vals=jnp.asarray(vs),
+            nnz_in_tile=jnp.asarray(nz),
+            perm=None if pp is None else jnp.asarray(pp),
+            tile=t.tile,
+            cap=t.cap,
+            shape=t.shape,
+            order=t.order,
+        )
 
 
 # ---------------------------------------------------------------------------

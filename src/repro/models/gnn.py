@@ -45,6 +45,7 @@ from repro.core.scv import (
     plan_from_tiles_bucketed,
 )
 from repro.models.layers import make_param, split_tree
+from repro.spans import span
 
 
 @jax.tree_util.register_pytree_node_class
@@ -130,9 +131,10 @@ def build_graph(
         tiles = coo_to_scv_tiles(adj, tile, cap=backend_cap)
         plan = plan_from_tiles(tiles)  # coverage dummies + perm padding, one path
     if with_edges:
-        rows, cols, vals = (
-            jnp.asarray(adj.rows), jnp.asarray(adj.cols), jnp.asarray(adj.vals),
-        )
+        with span("serve.plan.to_device"):
+            rows, cols, vals = (
+                jnp.asarray(adj.rows), jnp.asarray(adj.cols), jnp.asarray(adj.vals),
+            )
     else:
         rows = cols = vals = None
     return Graph(n_nodes=adj.shape[0], plan=plan, rows=rows, cols=cols, vals=vals)

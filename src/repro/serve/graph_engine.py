@@ -86,6 +86,7 @@ from repro.serve.scheduler import (
     Scheduler,
     _Control,
 )
+from repro.spans import span
 from repro.stream import DeltaBatch, apply_coo, apply_delta, check_delta
 from repro.tune.config import TunedConfig
 
@@ -128,6 +129,9 @@ class GraphRequest:
     error: Optional[str] = None  # set when ejected as failed or shed
     retries: int = 0  # failed waves this request has been part of
     isolate: bool = False  # re-serve alone (failure isolation)
+    # the latest wave this request was formed into: the ``wave=`` id of
+    # that wave's ``serve.*`` spans (see repro.spans)
+    wave: Optional[int] = None
     t_submit: float = 0.0  # time.monotonic() at admission
     t_done: float = 0.0  # time.monotonic() at completion
     # set on every terminal transition (completed / failed / shed) —
@@ -345,7 +349,7 @@ def _assemble_segment(
     vals2 = _cat([s.vals for s in segs], [np.zeros((n_pad, cap))], np.float32)
     nnz2 = _cat([s.nnz_in_tile for s in segs], [np.zeros(n_pad)], np.int32)
 
-    perm_j = None
+    perm = None
     if entry_off is not None:
         perm = np.full((nt + n_fill, cap), -1, np.int32)
         if k:
@@ -354,21 +358,21 @@ def _assemble_segment(
             perm[:nt_members] = np.where(
                 pstack >= 0, pstack + poff, -1
             ).astype(np.int32)
-        perm_j = jnp.asarray(perm)
 
-    return SCVPlan(
-        tile_row=jnp.asarray(tile_row),
-        tile_col=jnp.asarray(tile_col),
-        rows=jnp.asarray(rows2),
-        cols=jnp.asarray(cols2),
-        vals=jnp.asarray(vals2),
-        nnz_in_tile=jnp.asarray(nnz2),
-        perm=perm_j,
-        tile=T,
-        cap=cap,
-        shape=(pad_nodes, pad_nodes),
-        order=order,
-    )
+    with span("serve.plan.to_device"):
+        return SCVPlan(
+            tile_row=jnp.asarray(tile_row),
+            tile_col=jnp.asarray(tile_col),
+            rows=jnp.asarray(rows2),
+            cols=jnp.asarray(cols2),
+            vals=jnp.asarray(vals2),
+            nnz_in_tile=jnp.asarray(nnz2),
+            perm=None if perm is None else jnp.asarray(perm),
+            tile=T,
+            cap=cap,
+            shape=(pad_nodes, pad_nodes),
+            order=order,
+        )
 
 
 def assemble_batched_graph(
@@ -557,7 +561,6 @@ class GraphServeEngine:
         self.n_batches = 0  # composite waves served
         self.n_launches = 0  # actual pallas kernel launches (see plan_launches)
         self.n_sharded_batches = 0  # waves routed through the executor
-        self.serve_seconds = 0.0
         # tuner resolution + resolved-config bookkeeping are shared between
         # the producer thread (submit/registration) and the wave consumer
         self._tune_lock = threading.Lock()
@@ -631,6 +634,12 @@ class GraphServeEngine:
         when the bounded intake queue stays full (with ``block=False`` it
         fails fast; otherwise after ``timeout`` seconds — backpressure
         instead of unbounded queue growth)."""
+        with span("serve.submit", rid=req.rid):
+            return self._submit(req, block, timeout)
+
+    def _submit(
+        self, req: GraphRequest, block: bool, timeout: Optional[float]
+    ) -> GraphRequest:
         if req.model not in self.models:
             raise KeyError(f"unknown model {req.model!r}; have {list(self.models)}")
         if req.adj is not None:
@@ -814,53 +823,63 @@ class GraphServeEngine:
         state *here*, at wave time: their member key is the delta-chained
         key ``update()`` maintains, so a post-update wave can never hit a
         pre-delta composite (the composite key combines member keys)."""
-        adjs = [self._resolve_adj(r) for r in batch]
-        # members were grouped by resolved config at wave formation
-        # (Scheduler._pick_wave), so the head's resolution is the layout
-        tcfg = self._resolve_config(adjs[0])
-        T = tcfg.tile
-        _, mcfg = self.models[batch[0].model]
-        with_edges = mcfg.kind == "gat"
-        # the capacity layout is plan aux: it belongs in both key levels
-        # (a single-cap plan and a bucketed plan of the same graph are
-        # different device objects)
-        cap_sig = tcfg.cap_signature
-        member_keys = [
-            self._graphs[r.graph_id].key
-            if r.graph_id is not None
-            else coo_content_key(a, tile=T, cap=cap_sig)
-            for r, a in zip(batch, adjs)
-        ]
-        aligned = sum(-(-a.shape[0] // T) * T for a in adjs)
-        bucket = _bucket_nodes(aligned, self.cfg.node_buckets, T)
-        decision = self._shard_decision(adjs, bucket, mcfg)
-        ckey = combine_keys(
-            member_keys,
-            salt=f"batch;bucket={bucket};tile={T};caps={cap_sig};"
-            f"edges={int(with_edges)};"
-            f"shard={decision.signature if decision else 'none'};",
-        )
-
-        def build() -> BatchedGraph:
-            plans = [
-                self.plan_cache.get_or_build(
-                    k, lambda a=a: build_graph(a, config=tcfg)
+        w = batch[0].wave
+        with span("serve.plan", wave=w):
+            adjs = [self._resolve_adj(r) for r in batch]
+            # members were grouped by resolved config at wave formation
+            # (Scheduler._pick_wave), so the head's resolution is the layout
+            tcfg = self._resolve_config(adjs[0])
+            T = tcfg.tile
+            _, mcfg = self.models[batch[0].model]
+            with_edges = mcfg.kind == "gat"
+            # the capacity layout is plan aux: it belongs in both key levels
+            # (a single-cap plan and a bucketed plan of the same graph are
+            # different device objects)
+            cap_sig = tcfg.cap_signature
+            with span("serve.plan.key", wave=w):
+                member_keys = [
+                    self._graphs[r.graph_id].key
+                    if r.graph_id is not None
+                    else coo_content_key(a, tile=T, cap=cap_sig)
+                    for r, a in zip(batch, adjs)
+                ]
+                aligned = sum(-(-a.shape[0] // T) * T for a in adjs)
+                bucket = _bucket_nodes(aligned, self.cfg.node_buckets, T)
+                decision = self._shard_decision(adjs, bucket, mcfg)
+                ckey = combine_keys(
+                    member_keys,
+                    salt=f"batch;bucket={bucket};tile={T};caps={cap_sig};"
+                    f"edges={int(with_edges)};"
+                    f"shard={decision.signature if decision else 'none'};",
                 )
-                for k, a in zip(member_keys, adjs)
-            ]
-            bg = assemble_batched_graph(plans, T, bucket, with_edges=with_edges)
-            if decision is not None:
-                bg = dataclasses.replace(
-                    bg,
-                    graph=self.executor.prepare_graph(
-                        bg.graph, decision=decision
-                    ),
-                )
-            if self.cfg.debug_validate:
-                validate_plan(bg).raise_if_failed()
-            return bg
 
-        return self.plan_cache.get_or_build(ckey, build)
+            def build_member(a: COOMatrix, rid: int) -> Graph:
+                with span("serve.plan.build", rid=rid):
+                    return build_graph(a, config=tcfg)
+
+            def build() -> BatchedGraph:
+                plans = [
+                    self.plan_cache.get_or_build(
+                        k, lambda a=a, rid=r.rid: build_member(a, rid)
+                    )
+                    for k, a, r in zip(member_keys, adjs, batch)
+                ]
+                with span("serve.plan.assemble", wave=w):
+                    bg = assemble_batched_graph(
+                        plans, T, bucket, with_edges=with_edges
+                    )
+                if decision is not None:
+                    bg = dataclasses.replace(
+                        bg,
+                        graph=self.executor.prepare_graph(
+                            bg.graph, decision=decision
+                        ),
+                    )
+                if self.cfg.debug_validate:
+                    validate_plan(bg).raise_if_failed()
+                return bg
+
+            return self.plan_cache.get_or_build(ckey, build)
 
     # -- serving -----------------------------------------------------------
     def run(self) -> list[GraphRequest]:
@@ -892,7 +911,8 @@ class GraphServeEngine:
         overlap host-side assembly of the next wave (plan-cache lookups,
         composite concatenation) with this wave's device time."""
         bg, args = self._forward_args(wave)
-        return bg, gnn_forward_jit(*args)
+        with span("serve.dispatch", wave=wave[0].wave):
+            return bg, gnn_forward_jit(*args)
 
     def lower(self, wave: list[GraphRequest]):
         """The jitted forward that serving ``wave`` runs, lowered but not
@@ -905,34 +925,41 @@ class GraphServeEngine:
         """The wave's composite and the arguments of its jitted forward."""
         bg = self._batch_plan(wave)
         params, mcfg = self.models[wave[0].model]
-        x = batch_features(bg, [r.x for r in wave])
+        with span("serve.features", wave=wave[0].wave):
+            x = batch_features(bg, [r.x for r in wave])
         return bg, (params, mcfg, bg.graph, x)
 
     def _finish_wave(self, wave, bg, out) -> list[GraphRequest]:
         """Materialize a dispatched wave's outputs (blocks on the device),
         complete its requests, and account the wave."""
-        outs = split_outputs(bg, out)  # np.asarray: the device sync point
-        self.n_batches += 1
-        if isinstance(bg.graph.plan, ShardedPlan):
-            self.n_sharded_batches += 1
-        _, mcfg = self.models[wave[0].model]
-        # every model kind aggregates once per layer, so a wave costs
-        # (launches per aggregation) x n_layers kernel launches
-        self.n_launches += plan_launches(bg.graph.plan) * mcfg.n_layers
-        now = time.monotonic()
-        done = []
-        for r, o in zip(wave, outs):
-            r.out = o
-            r.done = True
-            r.t_done = now
-            self.completed.append(r)
-            self.n_completed += 1
-            if r.t_submit:
-                self.scheduler.record_latency(now - r.t_submit)
-            if r.event is not None:
-                r.event.set()
-            done.append(r)
-        return done
+        w = wave[0].wave
+        with span("serve.device_wait", wave=w):
+            out.block_until_ready()
+        with span("serve.fetch", wave=w):
+            host = np.asarray(out)
+        with span("serve.split", wave=w):
+            outs = split_outputs(bg, host)
+            self.n_batches += 1
+            if isinstance(bg.graph.plan, ShardedPlan):
+                self.n_sharded_batches += 1
+            _, mcfg = self.models[wave[0].model]
+            # every model kind aggregates once per layer, so a wave costs
+            # (launches per aggregation) x n_layers kernel launches
+            self.n_launches += plan_launches(bg.graph.plan) * mcfg.n_layers
+            now = time.monotonic()
+            done = []
+            for r, o in zip(wave, outs):
+                r.out = o
+                r.done = True
+                r.t_done = now
+                self.completed.append(r)
+                self.n_completed += 1
+                if r.t_submit:
+                    self.scheduler.record_latency(now - r.t_submit)
+                if r.event is not None:
+                    r.event.set()
+                done.append(r)
+            return done
 
     # -- terminal transitions (called by the scheduler) --------------------
     def _shed_request(self, req: GraphRequest, msg: str) -> None:
@@ -1008,7 +1035,6 @@ class GraphServeEngine:
             "latency_mean_s": lat["mean_s"],
             "service_ema_s": sched.service_emas(),
             "async_running": sched.running,
-            "serve_seconds": self.serve_seconds,
             "plan_cache_hits": s.hits,
             "plan_cache_misses": s.misses,
             "plan_cache_evictions": s.evictions,

@@ -58,6 +58,8 @@ from typing import TYPE_CHECKING, Callable, Optional
 
 import numpy as np
 
+from repro.spans import span
+
 if TYPE_CHECKING:  # import cycle: graph_engine imports this module
     from repro.serve.graph_engine import GraphRequest, GraphServeEngine
 
@@ -356,7 +358,17 @@ class Scheduler:
         ``target_wave_size`` keeps the queue position open and absorbs
         compatible arrivals until ``max_wave_delay_ms`` has elapsed since
         formation started — continuous batching instead of snapshotting.
+        The wave's members get its id (``GraphRequest.wave``), which the
+        ``serve.*`` spans of its later stages carry.
         """
+        with span("serve.form") as ids:
+            wave = self._form(absorb)
+            if wave:
+                ids["wave"] = wave[0].wave
+                ids["rids"] = tuple(r.rid for r in wave)
+            return wave
+
+    def _form(self, absorb: bool) -> list["GraphRequest"]:
         t_start = time.monotonic()
         items, n = self.queue.snapshot()
         if not items:
@@ -372,27 +384,28 @@ class Scheduler:
             return wave
         # mid-flight absorb: keep topping the wave up with compatible
         # arrivals until it is full or the delay budget is spent
-        while len(wave) < self.target_wave:
-            elapsed = time.monotonic() - t_start
-            budget = self.max_wave_delay_s - elapsed
-            if budget <= 0:
-                break
-            if not self.queue.wait_for_work(timeout=budget):
-                break
-            if self.queue.has_controls():
-                break  # controls are serialized with waves: apply first
-            items, n = self.queue.snapshot()
-            if not items:
-                continue
-            grown, remaining = self._pick_wave(wave + items)
-            if len(grown) <= len(wave):
-                break  # head-compatible arrivals exhausted
-            # _pick_wave keeps arrival order, so the existing wave is a
-            # prefix of the grown wave; commit removes only the new picks
-            # (identity, not ==: requests hold numpy leaves)
-            taken = {id(r) for r in wave}
-            self.queue.commit(n, [r for r in remaining if id(r) not in taken])
-            wave = grown
+        with span("serve.absorb"):
+            while len(wave) < self.target_wave:
+                elapsed = time.monotonic() - t_start
+                budget = self.max_wave_delay_s - elapsed
+                if budget <= 0:
+                    break
+                if not self.queue.wait_for_work(timeout=budget):
+                    break
+                if self.queue.has_controls():
+                    break  # controls are serialized with waves: apply first
+                items, n = self.queue.snapshot()
+                if not items:
+                    continue
+                grown, remaining = self._pick_wave(wave + items)
+                if len(grown) <= len(wave):
+                    break  # head-compatible arrivals exhausted
+                # _pick_wave keeps arrival order, so the existing wave is a
+                # prefix of the grown wave; commit removes only the new picks
+                # (identity, not ==: requests hold numpy leaves)
+                taken = {id(r) for r in wave}
+                self.queue.commit(n, [r for r in remaining if id(r) not in taken])
+                wave = grown
         self._record_fill(wave)
         return wave
 
@@ -400,6 +413,8 @@ class Scheduler:
         with self._stats_lock:
             self.n_waves += 1
             self._fill_sum += len(wave) / self.target_wave
+            for r in wave:
+                r.wave = self.n_waves
 
     @property
     def wave_fill(self) -> float:
@@ -432,27 +447,23 @@ class Scheduler:
         isolate/eject and re-raise, interrupts restore the wave untouched
         and consume no retries."""
         eng = self.engine
-        t0 = time.perf_counter()
         done = eng.last_completed = []
-        try:
-            while self.queue.depth():
-                wave = self.form_wave(absorb=False)
-                if not wave:
-                    continue  # everything shed
-                try:
-                    bg, out = eng._dispatch_wave(wave)
-                    done.extend(eng._finish_wave(wave, bg, out))
-                except BaseException as e:
-                    if not isinstance(e, Exception):
-                        # interrupts are not request failures: restore the
-                        # wave untouched, consume no retries
-                        self.queue.requeue(wave)
-                        raise
-                    self._fail_wave(wave, e)
+        while self.queue.depth():
+            wave = self.form_wave(absorb=False)
+            if not wave:
+                continue  # everything shed
+            try:
+                bg, out = eng._dispatch_wave(wave)
+                done.extend(eng._finish_wave(wave, bg, out))
+            except BaseException as e:
+                if not isinstance(e, Exception):
+                    # interrupts are not request failures: restore the
+                    # wave untouched, consume no retries
+                    self.queue.requeue(wave)
                     raise
-            return done
-        finally:
-            eng.serve_seconds += time.perf_counter() - t0
+                self._fail_wave(wave, e)
+                raise
+        return done
 
     # -- async loop --------------------------------------------------------
     def start(self) -> None:
@@ -567,9 +578,6 @@ class Scheduler:
                 return
             self._fail_wave(wave, e)
             return
-        finally:
-            dt = time.perf_counter() - t_wave
-            eng.serve_seconds += dt
         self._observe_service(wave[0].model, time.perf_counter() - t_wave)
 
     # -- introspection -----------------------------------------------------
