@@ -89,6 +89,29 @@ def dense_tile_threshold(tile: int) -> int:
     return int(tile * tile * MXU_VPU_RATIO)
 
 
+#: Largest capacity, as a share of the tile size T, of a segment the
+#: kernel serves with its row-gather body: fetching at most ``cap`` single
+#: Z rows costs less than the T-row block the vector body fetches, and a
+#: single-row DMA is taken to cost about two rows of a block transfer.
+ROW_GATHER_CAP_RATIO = 0.5
+#: Fewest tiles a segment needs for the row-gather body.  Below it the
+#: vector body's one grid step a tile (~0.4 µs on a v5e) costs under a
+#: millisecond a launch, less than the row-gather body's longer compile,
+#: which a stream of small-graph composites pays once per padding bucket
+#: (the molecule cell's cold set-up was 8.5 s longer with it).
+ROW_GATHER_MIN_TILES = 2048
+#: Entry slots one row-gather grid step covers: ``ROW_GATHER_SLOTS // cap``
+#: tiles per step, and the row buffer's height.
+ROW_GATHER_SLOTS = 512
+
+
+def uses_row_gather(cap: int, tile: int, n_tiles: int) -> bool:
+    """Whether a segment of ``n_tiles`` tiles of capacity ``cap`` at tile
+    size ``tile`` runs the kernel's row-gather body (DESIGN.md §2) rather
+    than the vector body."""
+    return cap <= tile * ROW_GATHER_CAP_RATIO and n_tiles >= ROW_GATHER_MIN_TILES
+
+
 def bucket_caps_for(
     counts: np.ndarray,
     tile: int,

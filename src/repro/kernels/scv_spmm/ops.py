@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.scv_spmm import ref as _ref
-from repro.kernels.scv_spmm.scv_spmm import scv_spmm_pallas
+from repro.kernels.scv_spmm.scv_spmm import resolve_body, scv_spmm_pallas
 
 
 #: Most tiles one kernel launch takes.  The three prefetched i32 tile
@@ -231,7 +231,7 @@ def scv_spmm(
     nnz_in_tile: jnp.ndarray | None = None,
     feature_block: int = 256,
     interpret: bool = False,
-    body: str = "vector",
+    body: str = "auto",
     chunk: int | None = None,
     dense_threshold: int | None = None,
 ) -> jnp.ndarray:
@@ -258,7 +258,8 @@ def scv_spmm(
         vals,
     )
     statics = (
-        tile, n_rows, feature_block, interpret, body,
+        tile, n_rows, feature_block, interpret,
+        resolve_body(body, vals.shape[1], tile, nt),
         int(DEFAULT_CHUNK if chunk is None else chunk), dense_threshold,
     )
     # one launch zero-initializes only the strips its tiles visit: a tile
@@ -275,7 +276,7 @@ def scv_spmm_plan(
     *,
     feature_block: int = 256,
     interpret: bool = False,
-    body: str = "vector",
+    body: str = "auto",
     chunk: int | None = None,
     dense_threshold: int | None = None,
     init: str = "coverage",
@@ -307,6 +308,12 @@ def scv_spmm_plan(
     instead: every row is then defined even when *no* segment covers it —
     the executor's sharded spans (which carry no per-span coverage) use
     this mode.
+
+    ``body="auto"`` (the default) lets each segment pick its kernel body
+    from its static ``(cap, tile)`` and tile count: the row-gather body
+    for large ultra-sparse segments, the vector body otherwise
+    (``scv_spmm.resolve_body``).  Every span of a segment runs the body
+    the whole segment resolved to.
 
     Under the executor's feature-axis sharding (``core.exec``), ``z`` is a
     device-local ``Z[:, f0:f1]`` slab: the kernel's feature-block grid
@@ -340,7 +347,8 @@ def scv_spmm_plan(
             seg.cols.astype(jnp.int32),
             seg.vals,
         )
-        statics = (seg.tile, n_rows, fb, interpret, body, chunk, dense_threshold)
+        seg_body = resolve_body(body, seg.cap, seg.tile, seg.tile_row.shape[0])
+        statics = (seg.tile, n_rows, fb, interpret, seg_body, chunk, dense_threshold)
         out = _chain(arrays, zp, out, statics)
     return out[:, :f_orig]
 

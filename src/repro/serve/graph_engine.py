@@ -67,6 +67,7 @@ from repro.core.scv import (
     DEFAULT_TILE,
     SCVBucketedPlan,
     SCVPlan,
+    uses_row_gather,
 )
 from repro.core.validate import check_coo, validate_plan
 from repro.kernels.scv_spmm.ops import n_launches
@@ -479,7 +480,7 @@ def assemble_batched_graph(
     )
 
 
-def plan_launches(plan) -> int:
+def plan_launches(plan, *, row_gather: bool = False) -> int:
     """Device kernel launches one aggregation over ``plan`` costs.
 
     A single-cap ``SCVPlan`` is one launch; a bucketed plan chains one
@@ -490,11 +491,20 @@ def plan_launches(plan) -> int:
     per-segment launches on every mesh instance
     (``tile_parts x feature_parts`` shard_map bodies).  The forward then
     multiplies by ``GNNConfig.n_layers`` — that factor is the caller's
-    (every model kind aggregates exactly once per layer)."""
-    if isinstance(plan, ShardedPlan):
-        per_device = sum(n_launches(s.tile_row.shape[-1]) for s in plan.segments)
-        return per_device * plan.decision.n_devices
+    (every model kind aggregates exactly once per layer).
+
+    ``row_gather=True`` counts only the launches of segments the kernel
+    serves with its row-gather body (``core.scv.uses_row_gather``; a
+    sharded segment decides by its span width, as its launches do)."""
     segments = getattr(plan, "segments", (plan,))
+    if row_gather:
+        segments = [
+            s for s in segments
+            if uses_row_gather(s.cap, s.tile, s.tile_row.shape[-1])
+        ]
+    if isinstance(plan, ShardedPlan):
+        per_device = sum(n_launches(s.tile_row.shape[-1]) for s in segments)
+        return per_device * plan.decision.n_devices
     return sum(n_launches(s.tile_row.shape[0]) for s in segments)
 
 
@@ -560,6 +570,7 @@ class GraphServeEngine:
         self.last_completed: list[GraphRequest] = []  # from the latest run()
         self.n_batches = 0  # composite waves served
         self.n_launches = 0  # actual pallas kernel launches (see plan_launches)
+        self.n_row_gather_launches = 0  # of those, row-gather body launches
         self.n_sharded_batches = 0  # waves routed through the executor
         # tuner resolution + resolved-config bookkeeping are shared between
         # the producer thread (submit/registration) and the wave consumer
@@ -946,6 +957,9 @@ class GraphServeEngine:
             # every model kind aggregates once per layer, so a wave costs
             # (launches per aggregation) x n_layers kernel launches
             self.n_launches += plan_launches(bg.graph.plan) * mcfg.n_layers
+            self.n_row_gather_launches += (
+                plan_launches(bg.graph.plan, row_gather=True) * mcfg.n_layers
+            )
             now = time.monotonic()
             done = []
             for r, o in zip(wave, outs):
@@ -1021,6 +1035,8 @@ class GraphServeEngine:
             # sharded) and the forward aggregates once per layer — see
             # plan_launches()
             "launches": self.n_launches,
+            # of those, launches of the kernel's row-gather body
+            "row_gather_launches": self.n_row_gather_launches,
             "completed": self.n_completed,
             "failed": self.n_failed,
             "shed": sched.n_shed,
