@@ -8,7 +8,8 @@ cols, float32 values) of the GCN-normalised adjacency
   way the program's ``simul.datasets.load`` draws them (Chung-Lu with
   Zipf weights, alpha 2.1, deduplicated directed edges, self loops added
   by the normalisation).  A copy, so that the yardstick stays fixed when
-  the program changes.
+  the program changes.  ``table_graph`` draws one per seed, or one per
+  dataset that each seed renames block by block (``relabel_blocks``).
 * ``molecules``: molecule-shaped graphs in bulk.  Each is a random tree
   of atoms with degree at most 4, closed into 5- and 6-rings, stored
   undirected (both directions) with self loops.  All molecules of a set
@@ -77,11 +78,34 @@ def chung_lu(nodes: int, edges: int, seed: int, alpha: float = 2.1) -> Coo:
 
 
 def table_graph(spec: dict, seed: int) -> Coo:
-    """A Table I graph at ``spec["scale"]`` of its published size."""
+    """A Table I graph at ``spec["scale"]`` of its published size.
+
+    Drawn from ``seed``; or, where ``spec`` has ``"block"``, drawn once
+    from the dataset's name and relabelled by ``seed`` (``relabel_blocks``),
+    so that every seed serves the same tiles in another order."""
     scale = float(spec.get("scale", 1.0))
     nodes = max(64, int(spec["nodes"] * scale))
     edges = max(256, int(spec["edges"] * scale))
+    if "block" in spec:
+        g = chung_lu(nodes, edges, name_seed(spec["name"]))
+        return relabel_blocks(g, int(spec["block"]), np.random.default_rng(seed))
     return chung_lu(nodes, edges, seed + name_seed(spec["name"]))
+
+
+def relabel_blocks(g: Coo, block: int, rng: np.random.Generator) -> Coo:
+    """``g`` with its nodes renamed: the whole blocks of ``block`` nodes
+    in a random order, and the nodes of each block shuffled within it
+    (the last, partial block stays last).  Every ``block`` x ``block``
+    tile keeps its entries, so the graph's tile histogram, and with it
+    the work of a tiled aggregation, does not change."""
+    n = g.n
+    full = n // block
+    new = np.empty(n, np.int64)
+    dest = rng.permutation(full)
+    within = rng.permuted(np.tile(np.arange(block), (full, 1)), axis=1)
+    new[: full * block] = (dest[:, None] * block + within).ravel()
+    new[full * block:] = rng.permutation(np.arange(full * block, n))
+    return Coo(new[g.rows].astype(np.int32), new[g.cols].astype(np.int32), g.vals, n)
 
 
 # ---------------------------------------------------------------------------

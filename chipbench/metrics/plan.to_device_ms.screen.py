@@ -1,7 +1,9 @@
-"""Host milliseconds per member plan build spent putting its arrays on the
-device: the ``serve.plan.to_device`` spans whose parent is a
-``serve.plan.build``, summed, over the number of member builds in the
-window."""
+"""Host milliseconds of plan puts per member plan build: every
+``serve.plan.to_device`` span in the window, whether under a member build
+(``serve.plan.build``) or a composite assembly (``serve.plan.assemble``),
+summed, over the number of member builds in the window.  So a program
+that puts a composite once, and its members not at all, still reads its
+puts, spread over the members."""
 from metrics._spans import in_window
 
 
@@ -9,9 +11,8 @@ def read(run):
     recs = in_window(run)
     if not recs:
         return None
-    builds = {r.sid for r in recs if r.name == "serve.plan.build"}
+    builds = sum(r.name == "serve.plan.build" for r in recs)
     if not builds:
         return None
-    ns = sum(r.end_ns - r.start_ns for r in recs
-             if r.name == "serve.plan.to_device" and r.parent in builds)
-    return ns / len(builds) / 1e6
+    ns = sum(r.end_ns - r.start_ns for r in recs if r.name == "serve.plan.to_device")
+    return ns / builds / 1e6

@@ -25,6 +25,27 @@ def test_molecule_sizes_are_the_same_multiset_for_every_seed():
     assert a.min() >= spec["min_atoms"] and a.max() <= spec["max_atoms"]
 
 
+def _tile_histogram(g, tile=64):
+    t = (g.rows // tile).astype(np.int64) * -(-g.n // tile) + g.cols // tile
+    counts = np.bincount(t)
+    return np.sort(counts[counts > 0])
+
+
+def test_a_blocked_table_graph_has_the_same_tiles_for_every_seed():
+    spec = {"kind": "table_i", "name": "tiny", "nodes": 1000, "edges": 6000, "scale": 1.0, "block": 64}
+    a, b = graphs.table_graph(spec, 1), graphs.table_graph(spec, 2)
+    assert np.array_equal(_tile_histogram(a), _tile_histogram(b))
+    assert not np.array_equal(a.rows, b.rows)
+    # a renaming of one graph: the same entries, and each node keeps its degree
+    assert sorted(a.vals.tolist()) == sorted(b.vals.tolist())
+    assert np.array_equal(np.sort(np.bincount(a.rows)), np.sort(np.bincount(b.rows)))
+    assert len(set(zip(a.rows.tolist(), a.cols.tolist()))) == len(set(zip(b.rows.tolist(), b.cols.tolist())))
+    # without the key, the seed draws another graph, tiles and all
+    del spec["block"]
+    c, d = graphs.table_graph(spec, 1), graphs.table_graph(spec, 2)
+    assert not np.array_equal(_tile_histogram(c), _tile_histogram(d))
+
+
 def test_molecules_are_undirected_bounded_and_normalised():
     spec = T.MOLECULES
     rng = np.random.default_rng(4)

@@ -48,6 +48,30 @@ def test_idle_gaps_are_labelled_by_host_spans():
     assert gaps[1] == ["chipbench.sleep", 2e-9]
 
 
+def test_idle_gaps_are_labelled_by_the_innermost_program_span():
+    """A gap inside ``chipbench.wait`` that holds ``serve.features`` is the
+    features' gap; of nested program spans the innermost names it, for
+    the longest part of the gap; a gap no program span reaches keeps the
+    benchmark's label, and one no span reaches has none."""
+    ops = [("op", 0, 10), ("op", 40, 50), ("op", 70, 80), ("op", 85, 90), ("op", 95, 100)]
+    spans = [("chipbench.window", 0, 110), ("chipbench.wait", 10, 80),
+             ("serve.features", 12, 38),
+             ("serve.plan", 50, 70), ("serve.plan.build", 52, 65), ("serve.plan.to_device", 55, 58),
+             ("chipbench.submit", 80, 90)]
+    gaps = tr.labelled_gaps(ops, spans, 0, 110)
+    assert gaps == [["serve.features", 30e-9], ["serve.plan.build", 20e-9],
+                    ["no benchmark span", 10e-9], ["chipbench.submit", 5e-9],
+                    ["no benchmark span", 5e-9]]
+    # spans that hold the whole gap: the one that started last is innermost
+    held = [("serve.form", 0, 60), ("serve.absorb", 5, 60), ("serve.fetch", 45, 48)]
+    assert tr.labelled_gaps(ops, held, 0, 60)[0] == ["serve.absorb", 30e-9]
+    # spans of two threads do not nest: a client's submit that starts
+    # inside the scheduler's build does not hide the build
+    threads = [("serve.plan", 10, 40, "sched"), ("serve.plan.build", 11, 40, "sched"),
+               ("serve.submit", 12, 30, "client")]
+    assert tr.labelled_gaps(ops, threads, 0, 60)[0] == ["serve.plan.build", 30e-9]
+
+
 def test_reduce_averages_busy_over_devices():
     t = tr.Trace(
         device_ops={"/device:TPU:0": [("scv_spmm", 0, 50)],
@@ -71,7 +95,8 @@ def test_reduce_needs_window_and_device_ops():
 
 def test_recorded_cpu_trace_holds_the_benchmark_spans(tmp_path):
     """A trace recorded here has no device plane, but the loader finds
-    the benchmark's host spans and the window in it."""
+    the benchmark's and the program's (``serve.*``) host spans and the
+    window in it, and drops other host events."""
     import jax
     import jax.numpy as jnp
 
@@ -83,11 +108,13 @@ def test_recorded_cpu_trace_holds_the_benchmark_spans(tmp_path):
     with jax.profiler.trace(str(tmp_path), profiler_options=opts):
         with harness.span("chipbench.window"):
             for _ in range(3):
-                with harness.span("chipbench.submit"):
+                with harness.span("chipbench.submit"), harness.span("serve.features"):
                     f(x).block_until_ready()
+            with harness.span("other.span"):
+                pass
     t = tr.load(str(tmp_path))
-    names = {n for n, _, _ in t.host_spans}
-    assert {"chipbench.window", "chipbench.submit"} <= names
+    names = {n for n, *_ in t.host_spans}
+    assert names == {"chipbench.window", "chipbench.submit", "serve.features"}
     lo, hi = tr.window(t)
     assert hi > lo
     assert any(p[0].startswith("/host:") for p in t.planes)
@@ -150,3 +177,23 @@ def test_benchmark_file_finds_every_piece_by_name():
         assert callable(harness.metric_reader(m["name"]).read)
     for m in bench["per_layer"]:
         assert m["moves"] in {e["name"] for e in bench["end_to_end"]}
+
+
+def _table_i_cells() -> list:
+    bench = json.loads((REPO / "BENCHMARK.json").read_text())
+    return [w["name"] for w in bench["workloads"]
+            if harness.cell_spec(bench, w["name"])[2]["source"]["kind"] == "table_i"]
+
+
+@pytest.mark.parametrize("workload", _table_i_cells())
+def test_full_graph_cells_run_at_their_graphs_widths(workload):
+    """A Table I cell's configuration reads as many input features as its
+    traffic's graph has (Table I, ``simul.datasets.TABLE_I``), and the
+    traffic draws the graph at its published nodes and edges."""
+    from repro.simul.datasets import TABLE_I
+
+    _, config, traffic = harness.cell_spec(harness.benchmark(), workload)
+    src = traffic["source"]
+    spec = TABLE_I[src["name"]]
+    assert config["model"]["d_in"] == spec.feature_size
+    assert (src["nodes"], src["edges"]) == (spec.nodes, spec.edges)

@@ -108,3 +108,37 @@ def test_each_wave_runs_its_stages_in_order(rehearsal):
     for w in whole:
         assert tuple(r.name for r in w) == STAGES
         assert all(a.end_ns <= b.start_ns for a, b in zip(w, w[1:]))
+
+
+def _synthetic_run(monkeypatch, recs):
+    """A run whose window, 0 to 1,000 ns, holds the spans ``recs``
+    (name, start, end, parent sid, sid), and nothing else."""
+    log = spans.SpanLog()
+    for name, s, e, parent, sid in recs:
+        log.add(spans.Span(name, s, e, parent, {}, sid))
+    monkeypatch.setattr(spans, "LOG", log)
+    return SimpleNamespace(window=SimpleNamespace(t_open=0.0, t_end=1e-6))
+
+
+@pytest.mark.parametrize("puts,per_build_ns", [
+    # every member build puts its own leaves
+    ([("serve.plan.to_device", 12, 42, 1, 11), ("serve.plan.to_device", 110, 130, 2, 12)], 25),
+    # members stay on the host; the assembly puts the composite once
+    ([("serve.plan.to_device", 320, 380, 3, 13)], 30),
+    # both: the assembly's put counts beside the members'
+    ([("serve.plan.to_device", 12, 42, 1, 11), ("serve.plan.to_device", 320, 380, 3, 13)], 45),
+], ids=["member_puts", "one_assembly_put", "both"])
+def test_plan_puts_count_under_builds_and_assemblies(monkeypatch, puts, per_build_ns):
+    """``plan.to_device_ms.screen`` sums every plan put in the window, under
+    a member build or an assembly, over the member builds."""
+    plan = [("serve.plan.build", 10, 50, None, 1), ("serve.plan.build", 100, 150, None, 2),
+            ("serve.plan.assemble", 300, 400, None, 3)]
+    run = _synthetic_run(monkeypatch, plan + puts)
+    got = harness.metric_reader("plan.to_device_ms.screen").read(run)
+    assert got == pytest.approx(per_build_ns / 1e6)
+
+
+def test_plan_puts_without_member_builds_read_nothing(monkeypatch):
+    run = _synthetic_run(monkeypatch, [("serve.plan.assemble", 300, 400, None, 3),
+                                       ("serve.plan.to_device", 320, 380, 3, 13)])
+    assert harness.metric_reader("plan.to_device_ms.screen").read(run) is None

@@ -2,12 +2,16 @@
 
 The JAX profiler writes ``<dir>/plugins/profile/<time>/*.xplane.pb``.
 Device operations are the events of the ``XLA Ops`` line of each
-``/device:TPU:<n>`` plane; the benchmark's own host spans are the events
-named ``chipbench.*`` on the host plane, on the same clock.
+``/device:TPU:<n>`` plane.  Host spans are the events on the host plane
+named ``chipbench.*`` (the benchmark's own) or ``serve.*`` (the program's,
+``repro.spans``), on the same clock; each keeps the line (the thread) it
+was recorded on.
 
 Busy time is the union of the device op intervals inside the window,
 averaged over the devices used; idle is the rest of the window.  A
-kernel's time is the sum of the durations of its events.
+kernel's time is the sum of the durations of its events.  Each long idle
+gap is labelled by what the host was doing in it: the program's span
+where one runs, else the benchmark's.
 """
 from __future__ import annotations
 
@@ -18,6 +22,7 @@ from collections import defaultdict
 
 OPS_LINE = "XLA Ops"
 SPAN_PREFIX = "chipbench."
+PROGRAM_PREFIX = "serve."
 WINDOW_SPAN = "chipbench.window"
 
 
@@ -26,7 +31,7 @@ class Trace:
     """Events as (name, start_ns, end_ns), device ops per device plane."""
 
     device_ops: dict  # plane name -> list of (name, start, end)
-    host_spans: list  # (name, start, end) of the benchmark's spans
+    host_spans: list  # (name, start, end, thread) of the benchmark's and the program's spans
     planes: list  # (plane name, [(line name, n events)]) for the record
 
 
@@ -57,17 +62,17 @@ def load(path: str) -> Trace:
             device_ops[plane.name] = ops
         elif plane.name.startswith("/host:"):
             host_spans += [
-                (ev.name, int(ev.start_ns), int(ev.start_ns + ev.duration_ns))
-                for ln in lines
+                (ev.name, int(ev.start_ns), int(ev.start_ns + ev.duration_ns), (plane.name, i))
+                for i, ln in enumerate(lines)
                 for ev in ln.events
-                if ev.name.startswith(SPAN_PREFIX)
+                if ev.name.startswith((SPAN_PREFIX, PROGRAM_PREFIX))
             ]
     return Trace(device_ops=device_ops, host_spans=host_spans, planes=planes)
 
 
 def window(tr: Trace) -> tuple[int, int]:
     """The measured window: the benchmark's ``chipbench.window`` span."""
-    spans = [(s, e) for n, s, e in tr.host_spans if n == WINDOW_SPAN]
+    spans = [(s, e) for n, s, e, *_ in tr.host_spans if n == WINDOW_SPAN]
     if not spans:
         raise ValueError("trace holds no chipbench.window span")
     return min(s for s, _ in spans), max(e for _, e in spans)
@@ -137,22 +142,51 @@ def top_ops(ops, lo: int, hi: int, k: int = 10) -> list:
 
 
 def labelled_gaps(ops, spans, lo: int, hi: int, k: int = 10) -> list:
-    """[[label, seconds]] of the ``k`` longest idle gaps in [lo, hi], each
-    labelled by the benchmark span that overlaps it most: what the host
-    was doing while the device had nothing to run."""
+    """[[label, seconds]] of the ``k`` longest idle gaps in [lo, hi]: what
+    the host was doing while the device had nothing to run.  A gap is
+    labelled by the program span (``serve.*``) that is innermost on its
+    thread for the longest part of it; where none runs in it, by the
+    benchmark span that overlaps it most; else "no benchmark span".  A
+    span is ``(name, start, end)`` or ``(name, start, end, thread)``;
+    spans without a thread count as one thread."""
     longest = sorted(gaps(ops, lo, hi), key=lambda g: g[0] - g[1])[:k]
+    program = [sp for sp in spans if sp[0].startswith(PROGRAM_PREFIX)]
+    bench = [sp for sp in spans if sp[0].startswith(SPAN_PREFIX) and sp[0] != WINDOW_SPAN]
     out = []
     for gs, ge in longest:
-        overlap: dict = defaultdict(int)
-        for n, s, e in spans:
-            if n == WINDOW_SPAN:
-                continue
-            o = min(e, ge) - max(s, gs)
-            if o > 0:
-                overlap[n] += o
-        label = max(overlap, key=overlap.get) if overlap else "no benchmark span"
-        out.append([label, (ge - gs) / 1e9])
+        label = _innermost_most(program, gs, ge) or _overlapping_most(bench, gs, ge)
+        out.append([label or "no benchmark span", (ge - gs) / 1e9])
     return out
+
+
+def _overlapping_most(spans, gs: int, ge: int):
+    """The name whose spans overlap [gs, ge] longest, or None."""
+    overlap: dict = defaultdict(int)
+    for n, s, e, *_ in spans:
+        o = min(e, ge) - max(s, gs)
+        if o > 0:
+            overlap[n] += o
+    return max(overlap, key=overlap.get) if overlap else None
+
+
+def _innermost_most(spans, gs: int, ge: int):
+    """The name that is innermost on its thread for the longest part of
+    [gs, ge], that time summed over threads, or None.  On one thread the
+    innermost of the spans running at an instant is the one that started
+    last (the shortest, where two start together): a nested span starts
+    after the span that holds it.  Spans of two threads do not nest, so
+    each thread names its own at every instant."""
+    inside = [(n, s, e, tuple(rest)) for n, s, e, *rest in spans if s < ge and e > gs]
+    cuts = sorted({min(max(t, gs), ge) for _, s, e, _ in inside for t in (s, e)})
+    held: dict = defaultdict(int)
+    for a, b in zip(cuts, cuts[1:]):
+        innermost: dict = {}
+        for n, s, e, thread in inside:
+            if s <= a and b <= e:
+                innermost[thread] = max(innermost.get(thread, (s, -e, n)), (s, -e, n))
+        for _, _, n in innermost.values():
+            held[n] += b - a
+    return max(held, key=held.get) if held else None
 
 
 @dataclasses.dataclass
