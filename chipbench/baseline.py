@@ -9,7 +9,7 @@ import time
 
 import numpy as np
 
-from harness import log
+from harness import kind_module, log
 
 #: host-clock timings span at least this long, many calls together
 MIN_TIMED_S = 0.5
@@ -38,12 +38,12 @@ def segment_sum_seconds(coo, width: int, seed: int) -> float:
 
 
 def report(work, reduced, n_requests: int) -> None:
-    """Print the baseline's time per request (one aggregation per layer
-    at that layer's width) beside the kernel's, and their ratio."""
-    from cost import layer_dims
-
+    """Print the baseline's time per request (one segment-sum for each
+    aggregation of the model's kind, at its width) beside the kernel's,
+    and their ratio."""
     coo = work.source.coo
-    widths = [do for _, do in layer_dims(work.config["model"])]
+    model = work.config["model"]
+    widths = [f for f, _, _ in kind_module(model).aggregations(model, coo.n, coo.nnz)]
     base = sum(segment_sum_seconds(coo, f, work.seed) for f in widths)
     kernel = reduced.kernel_s.get("scv_spmm", 0.0) / max(n_requests, 1)
     log(f"baseline: XLA COO segment-sum per request (F={'+'.join(map(str, widths))}) "

@@ -2,12 +2,15 @@
 
 These are the yardstick's own counts: what the work needs, whatever
 implements it.  Roofline shares and model FLOP/s utilisation divide them
-by measured times.
+by measured times.  What a forward runs depends on the model's kind, so
+the counts of a whole forward come from the kind's module.
 """
 from __future__ import annotations
 
 import json
 import pathlib
+
+from harness import kind_module
 
 PEAKS = pathlib.Path(__file__).resolve().parent / "peaks.json"
 
@@ -38,24 +41,17 @@ def least_time(flops: float, nbytes: float, peak: dict) -> tuple[float, str]:
     return (t_flops, "flops") if t_flops >= t_bytes else (t_bytes, "bytes")
 
 
-def layer_dims(model: dict) -> list[tuple[int, int]]:
-    """(d_in, d_out) of each layer."""
-    dims = [model["d_in"]] + [model["d_hidden"]] * (model["n_layers"] - 1) + [model["n_classes"]]
-    return list(zip(dims[:-1], dims[1:]))
-
-
 def model_flops(model: dict, n: int, nnz: int) -> float:
-    """Operations of one forward over a graph: per layer the combination
-    ``2 n d_in d_out`` and the aggregation ``2 nnz d_out``."""
-    return sum(2.0 * n * di * do + 2.0 * nnz * do for di, do in layer_dims(model))
+    """Operations of one forward over a graph, as the model's kind counts
+    them (``kinds/<kind>.py``)."""
+    return kind_module(model).model_flops(model, n, nnz)
 
 
 def aggregation_least_time(model: dict, n: int, nnz: int, peak: dict) -> tuple[float, str]:
-    """Least time of one forward's aggregations; the bound named is that
-    of the widest layer."""
+    """Least time of one forward's aggregations (the kind's
+    ``aggregations``); the bound named is that of the last."""
     total, bound = 0.0, "bytes"
-    for _, do in layer_dims(model):
-        t, b = least_time(*aggregation_work(n, nnz, do), peak)
+    for _, flops, nbytes in kind_module(model).aggregations(model, n, nnz):
+        t, bound = least_time(flops, nbytes, peak)
         total += t
-        bound = b
     return total, bound

@@ -10,6 +10,15 @@ found by name in files of its own:
   the graph source;
 * ``metrics/<metric>.py``: one reader per metric, ``read(run)``, which
   returns a number or ``None`` where it finds nothing to read;
+* ``kinds/<kind>.py``: everything that depends on the shape of a model
+  kind (the configuration's ``model.kind``), as functions of the
+  configuration's ``model``: ``weights(model, key)``, the device pytree
+  the program's ``gnn_forward`` takes; ``program_config(model)``,
+  ``GNNConfig``'s fields other than ``name`` and ``backend``;
+  ``host_weights(params, model)``, what the reference's ``forward`` takes
+  as weights; ``model_flops(model, n, nnz)``; and ``aggregations(model,
+  n, nnz)``, one ``(width, flops, bytes)`` for each aggregation a forward
+  runs, in order;
 * the reference a configuration names (``reference.py`` for GCN).
 
 The system under test is ``repro.serve.graph_engine.GraphServeEngine``,
@@ -33,6 +42,7 @@ import numpy as np
 
 ROOT = pathlib.Path(__file__).resolve().parent
 REPO = ROOT.parent
+KINDS = ROOT / "kinds"
 #: a request the window waits for after it closes before calling it missing
 WAIT_AFTER_CLOSE_S = 60.0
 
@@ -91,30 +101,24 @@ def metric_reader(name: str):
     return load_module(ROOT / "metrics" / f"{name}.py")
 
 
+def kind_module(model: dict):
+    """The module of the model's kind, ``kinds/<kind>.py``; an unknown kind
+    is an error that names the kinds found."""
+    path = KINDS / f"{model['kind']}.py"
+    if not path.exists():
+        known = sorted(p.stem for p in KINDS.glob("*.py"))
+        raise KeyError(f"no model kind {model['kind']!r} in {KINDS}; known: {known}")
+    return load_module(path)
+
+
 # ---------------------------------------------------------------------------
 # the deployment: model, weights, engine
 # ---------------------------------------------------------------------------
 def make_weights(model: dict, seed: int):
-    """Glorot-normal weights for each layer, made on the device in one
-    jitted call from the seed, in float32 (the type they are served in)."""
+    """The kind's weights, made on the device from the seed."""
     import jax
-    import jax.numpy as jnp
 
-    from cost import layer_dims
-
-    dims = layer_dims(model)
-
-    @jax.jit
-    def build(key):
-        keys = jax.random.split(key, len(dims))
-        return {
-            f"layer{i}": {
-                "w": jax.random.normal(k, (di, do), jnp.float32) * np.sqrt(2.0 / (di + do))
-            }
-            for i, (k, (di, do)) in enumerate(zip(keys, dims))
-        }
-
-    return build(jax.random.PRNGKey(derived_seed(seed, "weights")))
+    return kind_module(model).weights(model, jax.random.PRNGKey(derived_seed(seed, "weights")))
 
 
 def derived_seed(seed: int, what: str) -> int:
@@ -131,10 +135,7 @@ def build_engine(config: dict, params, full_graph_nodes: Optional[int], backend:
     from repro.serve.graph_engine import GraphEngineConfig, GraphServeEngine
 
     m = config["model"]
-    mcfg = GNNConfig(
-        name=config["name"], kind=m["kind"], d_in=m["d_in"], d_hidden=m["d_hidden"],
-        n_classes=m["n_classes"], n_layers=m["n_layers"], backend=backend,
-    )
+    mcfg = GNNConfig(name=config["name"], backend=backend, **kind_module(m).program_config(m))
     kw = dict(config.get("engine", {}))
     if full_graph_nodes is not None:
         defaults = GraphEngineConfig()
@@ -473,8 +474,9 @@ class Workload:
     def stop(self) -> None:
         self.engine.stop(timeout=WAIT_AFTER_CLOSE_S)
 
-    def weights_host(self) -> list:
-        return [np.asarray(self.params[f"layer{i}"]["w"]) for i in range(self.mcfg.n_layers)]
+    def weights_host(self):
+        model = self.config["model"]
+        return kind_module(model).host_weights(self.params, model)
 
 
 # ---------------------------------------------------------------------------

@@ -137,7 +137,7 @@ def test_work_counts():
     assert bound == "bytes" and t == pytest.approx(nbytes / 819e9)
     t, bound = cost.least_time(1e15, 1.0, peak)
     assert bound == "flops" and t == pytest.approx(1e15 / 197e12)
-    model = {"d_in": 128, "d_hidden": 128, "n_classes": 40, "n_layers": 2}
+    model = {"kind": "gcn", "d_in": 128, "d_hidden": 128, "n_classes": 40, "n_layers": 2}
     # the arxiv-shaped request: ~7.8 GFLOP of which the aggregations ~0.45
     total = cost.model_flops(model, 169_343, 1_335_586)
     assert total == pytest.approx(2 * 169_343 * (128 * 128 + 128 * 40) + 2 * 1_335_586 * 168)
@@ -159,13 +159,17 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 
 
 def test_benchmark_file_finds_every_piece_by_name():
-    """Every name in BENCHMARK.json has its file: configuration, traffic
-    mix and metric reader; every cell reports set-up, another end-to-end
-    metric and a per-layer metric."""
+    """Every name in BENCHMARK.json has its file: configuration, its
+    model's kind (with the functions the harness calls), traffic mix and
+    metric reader; every cell reports set-up, another end-to-end metric
+    and a per-layer metric."""
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
     assert bench["paths"] == ["chipbench"]
     for c in bench["configs"]:
         assert NAME.match(c["name"]) and (REPO / c["file"]).exists()
+        kind = harness.kind_module(harness.read_json(REPO / c["file"])["model"])
+        for fn in ("weights", "program_config", "host_weights", "model_flops", "aggregations"):
+            assert callable(getattr(kind, fn)), (c["name"], fn)
     for w in bench["workloads"]:
         assert NAME.match(w["name"]) and len(w["why"]) <= 200
         harness.cell_spec(bench, w["name"])
