@@ -22,7 +22,9 @@ Two representations are provided:
   a whole GNN forward over it can sit under one ``jax.jit``.  Array fields
   are pytree **leaves**; ``tile`` / ``cap`` / ``shape`` / ``order`` are
   **static aux data**, so jit specializes on them (and on leaf shapes)
-  exactly once per padding bucket.
+  exactly once per padding bucket.  The host stage of plan construction
+  (``host_plan_from_tiles``) returns the same pytree with numpy leaves;
+  ``to_device`` puts it in one transfer.
 
 * :class:`SCVBucketedPlan` — the nnz-bucketed variant (DESIGN.md §2): one
   ``SCVPlan`` segment per entry-capacity bucket so a single hub tile no
@@ -564,7 +566,9 @@ class SCVPlan:
     * **Leaves** — the device arrays ``tile_row``, ``tile_col``, ``rows``,
       ``cols``, ``vals``, ``nnz_in_tile``, ``perm``.  They trace through
       ``jax.jit`` / ``shard_map`` / ``jax.grad`` like any other argument.
-      ``perm`` may be ``None`` (plans that never re-weight edges).
+      ``perm`` may be ``None`` (plans that never re-weight edges).  A
+      host-stage plan (``host_plan_from_tiles``) holds numpy arrays in
+      the same places, with the dtypes the device would hold.
     * **Static aux data** — ``tile``, ``cap``, ``shape``, ``order``.  jit
       specializes on them (plus leaf shapes); two plans with equal aux and
       equal array shapes share one trace, which is what bounds recompiles
@@ -634,18 +638,34 @@ class SCVPlan:
         return self.with_vals(ev[self.perm].astype(self.vals.dtype))
 
 
-def plan_from_tiles(
+def to_device(tree):
+    """Put a host-stage plan pytree (``SCVPlan``, ``SCVBucketedPlan``,
+    ``models.gnn.Graph``) on the default device: one batched transfer of
+    all its leaves, dtypes canonicalized as ``jnp.asarray`` would."""
+    with span("serve.plan.to_device"):
+        return jax.device_put(tree)
+
+
+def _host_leaf(a: np.ndarray) -> np.ndarray:
+    """A plan leaf as the host stage keeps it: contiguous (a truncated
+    bucket view would pin its wider parent), in the dtype the device
+    would hold."""
+    return np.ascontiguousarray(a, dtype=jax.dtypes.canonicalize_dtype(a.dtype))
+
+
+def host_plan_from_tiles(
     t: SCVTiles, ensure_coverage: bool = True, with_perm: bool = True
 ) -> SCVPlan:
-    """SCVTiles (host) -> SCVPlan (device pytree).
+    """SCVTiles -> SCVPlan with numpy leaves: the host stage of
+    :func:`plan_from_tiles`, for callers that only read the plan on the
+    host (the serving engine's member plans, which composite assembly
+    concatenates before the composite's one put).
 
     The single code path for coverage-dummy insertion and perm padding:
     every consumer (single-graph ``build_graph``, the serving engine's
     composite assembly, ``scv_device_arrays``) builds plans here, so the
     "dummy rows carry perm == -1" invariant lives in exactly one place.
     """
-    import jax.numpy as jnp
-
     tr, tc, rs, cs, vs, nz = (
         t.tile_row, t.tile_col, t.rows, t.cols, t.vals, t.nnz_in_tile,
     )
@@ -663,20 +683,27 @@ def plan_from_tiles(
             )
         pp = np.full((len(tr), t.cap), -1, np.int32)
         pp[: t.perm.shape[0]] = t.perm.astype(np.int32)
-    with span("serve.plan.to_device"):
-        return SCVPlan(
-            tile_row=jnp.asarray(tr),
-            tile_col=jnp.asarray(tc),
-            rows=jnp.asarray(rs),
-            cols=jnp.asarray(cs),
-            vals=jnp.asarray(vs),
-            nnz_in_tile=jnp.asarray(nz),
-            perm=None if pp is None else jnp.asarray(pp),
-            tile=t.tile,
-            cap=t.cap,
-            shape=t.shape,
-            order=t.order,
-        )
+    return SCVPlan(
+        tile_row=_host_leaf(tr),
+        tile_col=_host_leaf(tc),
+        rows=_host_leaf(rs),
+        cols=_host_leaf(cs),
+        vals=_host_leaf(vs),
+        nnz_in_tile=_host_leaf(nz),
+        perm=pp,
+        tile=t.tile,
+        cap=t.cap,
+        shape=t.shape,
+        order=t.order,
+    )
+
+
+def plan_from_tiles(
+    t: SCVTiles, ensure_coverage: bool = True, with_perm: bool = True
+) -> SCVPlan:
+    """SCVTiles (host) -> SCVPlan (device pytree): the host stage
+    (:func:`host_plan_from_tiles`) put to the device."""
+    return to_device(host_plan_from_tiles(t, ensure_coverage, with_perm))
 
 
 # ---------------------------------------------------------------------------
@@ -814,14 +841,15 @@ class SCVBucketedPlan:
         )
 
 
-def plan_from_tiles_bucketed(
+def host_plan_from_tiles_bucketed(
     t: SCVTiles,
     caps=None,
     ensure_coverage: bool = True,
     with_perm: bool = True,
     config=None,
 ) -> SCVBucketedPlan:
-    """SCVTiles (host) -> nnz-bucketed device plan.
+    """SCVTiles -> nnz-bucketed plan with numpy leaves (the host stage of
+    :func:`plan_from_tiles_bucketed`).
 
     ``caps`` defaults to :func:`bucket_caps_for` over the tile nnz
     histogram; a ``repro.tune.TunedConfig`` may be passed as ``config``
@@ -842,13 +870,27 @@ def plan_from_tiles_bucketed(
     segs = bucket_tiles(t, caps)
     return SCVBucketedPlan(
         tuple(
-            plan_from_tiles(
+            host_plan_from_tiles(
                 s,
                 ensure_coverage=(ensure_coverage and j == 0),
                 with_perm=with_perm,
             )
             for j, s in enumerate(segs)
         )
+    )
+
+
+def plan_from_tiles_bucketed(
+    t: SCVTiles,
+    caps=None,
+    ensure_coverage: bool = True,
+    with_perm: bool = True,
+    config=None,
+) -> SCVBucketedPlan:
+    """SCVTiles (host) -> nnz-bucketed device plan: the host stage
+    (:func:`host_plan_from_tiles_bucketed`) put to the device."""
+    return to_device(
+        host_plan_from_tiles_bucketed(t, caps, ensure_coverage, with_perm, config)
     )
 
 

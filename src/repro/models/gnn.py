@@ -41,11 +41,11 @@ from repro.core.scv import (
     SCVBucketedPlan,
     SCVPlan,
     coo_to_scv_tiles,
-    plan_from_tiles,
-    plan_from_tiles_bucketed,
+    host_plan_from_tiles,
+    host_plan_from_tiles_bucketed,
+    to_device,
 )
 from repro.models.layers import make_param, split_tree
-from repro.spans import span
 
 
 @jax.tree_util.register_pytree_node_class
@@ -57,7 +57,8 @@ class Graph:
     arrays (``rows`` / ``cols`` / ``vals`` — only GAT's attention reads
     them; batched composites may omit them, see
     ``serve.graph_engine.assemble_batched_graph``).  Static aux:
-    ``n_nodes``.
+    ``n_nodes``.  ``build_host_graph`` returns one with numpy leaves,
+    which ``core.scv.to_device`` puts.
     """
 
     n_nodes: int
@@ -74,7 +75,7 @@ class Graph:
         return cls(aux[0], *children)
 
 
-def build_graph(
+def build_host_graph(
     adj: COOMatrix,
     tile: int = DEFAULT_TILE,
     backend_cap: Optional[int] = None,
@@ -82,7 +83,9 @@ def build_graph(
     bucket_caps=None,
     config=None,
 ) -> Graph:
-    """COO adjacency -> device-ready :class:`Graph`.
+    """COO adjacency -> :class:`Graph` with numpy leaves: the host stage
+    of :func:`build_graph`.  The serving engine keeps its member plans
+    here, since composite assembly reads them only on the host.
 
     ``bucket_caps`` selects the nnz-bucketed plan layout: ``"auto"``
     derives the capacity ladder from the tile nnz histogram
@@ -126,18 +129,34 @@ def build_graph(
         # chain-split heavy tiles at the ladder's largest cap so every
         # chain fits some bucket
         tiles = coo_to_scv_tiles(adj, tile, cap=caps[-1])
-        plan = plan_from_tiles_bucketed(tiles, caps=caps)
+        plan = host_plan_from_tiles_bucketed(tiles, caps=caps)
     else:
         tiles = coo_to_scv_tiles(adj, tile, cap=backend_cap)
-        plan = plan_from_tiles(tiles)  # coverage dummies + perm padding, one path
+        plan = host_plan_from_tiles(tiles)  # coverage dummies + perm padding, one path
+    rows = cols = vals = None
     if with_edges:
-        with span("serve.plan.to_device"):
-            rows, cols, vals = (
-                jnp.asarray(adj.rows), jnp.asarray(adj.cols), jnp.asarray(adj.vals),
-            )
-    else:
-        rows = cols = vals = None
+        # copies: the plan must not alias the caller's adjacency
+        rows, cols, vals = (
+            np.array(a, jax.dtypes.canonicalize_dtype(a.dtype))
+            for a in (adj.rows, adj.cols, adj.vals)
+        )
     return Graph(n_nodes=adj.shape[0], plan=plan, rows=rows, cols=cols, vals=vals)
+
+
+def build_graph(
+    adj: COOMatrix,
+    tile: int = DEFAULT_TILE,
+    backend_cap: Optional[int] = None,
+    with_edges: bool = True,
+    bucket_caps=None,
+    config=None,
+) -> Graph:
+    """COO adjacency -> device-ready :class:`Graph`: the host stage
+    (:func:`build_host_graph`, which documents the arguments) put to the
+    device."""
+    return to_device(
+        build_host_graph(adj, tile, backend_cap, with_edges, bucket_caps, config)
+    )
 
 
 def _agg(g: Graph, z, edge_vals=None, backend="jnp"):

@@ -18,10 +18,12 @@ Three mechanisms make this a serving system rather than a loop:
    members' ``SCVPlan`` pytrees: member tile coordinates are shifted by
    the member's block offset and the plan leaves concatenated (vectorized
    numpy — no Python loop over tiles).  No re-tiling, no re-sorting, no
-   COO scan.  The block-diagonal structure guarantees the result equals
-   per-graph aggregation stacked (``core.formats.block_diag_coo`` is the
-   reference construction; ``tests/test_serve_graph.py`` checks both
-   agree).  The composite COO edge arrays + perm are built only when the
+   COO scan.  Member plans stay on the host (numpy leaves, as
+   ``build_host_graph`` leaves them); only the composite goes to the
+   device, in one put.  The block-diagonal structure guarantees the
+   result equals per-graph aggregation stacked
+   (``core.formats.block_diag_coo`` is the reference construction;
+   ``tests/test_serve_graph.py`` checks both agree).  The composite COO edge arrays + perm are built only when the
    batch's model kind needs them (GAT) — which puts the model-kind
    component into the composite cache key (see ``_batch_plan``).
 
@@ -56,7 +58,7 @@ import time
 from collections import deque
 from typing import Optional
 
-import jax.numpy as jnp
+import jax
 import numpy as np
 
 from repro.core.exec import ShardedPlan
@@ -67,6 +69,7 @@ from repro.core.scv import (
     DEFAULT_TILE,
     SCVBucketedPlan,
     SCVPlan,
+    to_device,
     uses_row_gather,
 )
 from repro.core.validate import check_coo, validate_plan
@@ -76,7 +79,7 @@ from repro.models.gnn import (
     GNNConfig,
     Graph,
     batch_features,
-    build_graph,
+    build_host_graph,
     gnn_forward_jit,
     split_outputs,
 )
@@ -191,6 +194,7 @@ class GraphEngineConfig:
     autotune_calibrate: bool = False
     node_buckets: tuple[int, ...] = (256, 512, 1024, 2048, 4096)
     cache_entries: int = 256
+    # bounds host-held member plans plus device-held composites together
     cache_bytes: int = 256 << 20
     plan_ttl_s: Optional[float] = None  # expire cached plans after this age
     completed_history: int = 1024  # recent requests kept for inspection
@@ -296,7 +300,8 @@ def _assemble_segment(
     entry_off: Optional[np.ndarray],
     first_segment: bool = True,
 ) -> SCVPlan:
-    """Fuse one capacity segment across members into the composite segment.
+    """Fuse one capacity segment across members into the composite segment
+    (numpy leaves; ``assemble_batched_graph`` puts the whole composite).
 
     Member tile coordinates shift by the member's block offset; then two
     pad blocks follow: fresh zero-nnz coverage tiles for the bucket-padding
@@ -360,20 +365,38 @@ def _assemble_segment(
                 pstack >= 0, pstack + poff, -1
             ).astype(np.int32)
 
-    with span("serve.plan.to_device"):
-        return SCVPlan(
-            tile_row=jnp.asarray(tile_row),
-            tile_col=jnp.asarray(tile_col),
-            rows=jnp.asarray(rows2),
-            cols=jnp.asarray(cols2),
-            vals=jnp.asarray(vals2),
-            nnz_in_tile=jnp.asarray(nnz2),
-            perm=None if perm is None else jnp.asarray(perm),
-            tile=T,
-            cap=cap,
-            shape=(pad_nodes, pad_nodes),
-            order=order,
-        )
+    return SCVPlan(
+        tile_row=tile_row,
+        tile_col=tile_col,
+        rows=rows2,
+        cols=cols2,
+        vals=vals2,
+        nnz_in_tile=nnz2,
+        perm=perm,
+        tile=T,
+        cap=cap,
+        shape=(pad_nodes, pad_nodes),
+        order=order,
+    )
+
+
+def _member_segments(g: Graph) -> tuple[SCVPlan, ...]:
+    return g.plan.segments if isinstance(g.plan, SCVBucketedPlan) else (g.plan,)
+
+
+def _assembly_fetches(plans: list[Graph], with_edges: bool) -> int:
+    """Member leaves that ``assemble_batched_graph`` reads off a device:
+    each segment's tile arrays and, with edges, its perm and the member's
+    COO edge arrays.  Zero for host-stage members."""
+    read = [
+        (s.tile_row, s.tile_col, s.rows, s.cols, s.vals, s.nnz_in_tile)
+        + ((s.perm,) if with_edges else ())
+        for g in plans
+        for s in _member_segments(g)
+    ]
+    if with_edges:
+        read += [(g.rows, g.cols, g.vals) for g in plans]
+    return sum(isinstance(x, jax.Array) for x in jax.tree_util.tree_leaves(read))
 
 
 def assemble_batched_graph(
@@ -385,11 +408,14 @@ def assemble_batched_graph(
     composite is index arithmetic over the members' plan pytrees: member
     i's tile coordinates shift by ``starts[i] // tile`` and its COO
     rows/cols by ``starts[i]`` — all of it vectorized numpy (concatenate +
-    broadcast adds), no per-tile Python loop.  Member coverage dummies
-    stay valid (each composite block-row belongs to exactly one member, so
-    PS block-row contiguity is preserved), and the bucket-padding rows at
-    the tail get fresh zero-nnz coverage tiles so the Pallas kernel
-    defines the whole output.
+    broadcast adds), no per-tile Python loop.  Members are read where
+    they are: the engine's host-stage members (``build_host_graph``) in
+    place, device-leaf members (``build_graph``) through ``np.asarray``.
+    The composite is put to the device once, all leaves in one transfer.
+    Member coverage dummies stay valid (each composite block-row belongs
+    to exactly one member, so PS block-row contiguity is preserved), and
+    the bucket-padding rows at the tail get fresh zero-nnz coverage tiles
+    so the Pallas kernel defines the whole output.
 
     Members carrying nnz-bucketed ``SCVBucketedPlan``s (all on the same
     capacity ladder) compose segment-by-segment — segment j of the
@@ -441,9 +467,7 @@ def assemble_batched_graph(
                 raise ValueError(
                     "with_edges=True needs member plans built with edges/perm"
                 )
-        edge_counts = np.array(
-            [int(np.asarray(g.rows).shape[0]) for g in plans], np.int64
-        )
+        edge_counts = np.array([g.rows.shape[0] for g in plans], np.int64)
         entry_off = np.concatenate([[0], np.cumsum(edge_counts)])
         if entry_off[-1] >= 2**31:  # composite perm is i32
             raise ValueError(
@@ -453,24 +477,21 @@ def assemble_batched_graph(
         rows = _cat([g.rows for g in plans], [], np.int64)
         cols = _cat([g.cols for g in plans], [], np.int64)
         eshift = np.repeat(starts[:k], edge_counts)
-        erows = jnp.asarray((rows + eshift).astype(np.int32))
-        ecols = jnp.asarray((cols + eshift).astype(np.int32))
-        evals = jnp.asarray(_cat([g.vals for g in plans], [], np.float32))
-
-    def member_segments(g: Graph) -> tuple[SCVPlan, ...]:
-        return g.plan.segments if isinstance(g.plan, SCVBucketedPlan) else (g.plan,)
+        erows = (rows + eshift).astype(np.int32)
+        ecols = (cols + eshift).astype(np.int32)
+        evals = _cat([g.vals for g in plans], [], np.float32)
 
     composed = [
         _assemble_segment(
-            [member_segments(g)[j] for g in plans],
+            [_member_segments(g)[j] for g in plans],
             blk_off, n_aligned, pad_nodes, T, cap, order, entry_off,
             first_segment=(j == 0),
         )
         for j, cap in enumerate(ladder)
     ]
     plan = SCVBucketedPlan(tuple(composed)) if bucketed else composed[0]
-    graph = Graph(
-        n_nodes=pad_nodes, plan=plan, rows=erows, cols=ecols, vals=evals
+    graph = to_device(
+        Graph(n_nodes=pad_nodes, plan=plan, rows=erows, cols=ecols, vals=evals)
     )
     return BatchedGraph(
         graph=graph,
@@ -572,6 +593,10 @@ class GraphServeEngine:
         self.n_launches = 0  # actual pallas kernel launches (see plan_launches)
         self.n_row_gather_launches = 0  # of those, row-gather body launches
         self.n_sharded_batches = 0  # waves routed through the executor
+        # plan arrays the plan path moves: host->device (the composite's
+        # one put) and device->host (a device-leaf member read by assembly)
+        self.n_plan_leaf_puts = 0
+        self.n_plan_leaf_fetches = 0
         # tuner resolution + resolved-config bookkeeping are shared between
         # the producer thread (submit/registration) and the wave consumer
         self._tune_lock = threading.Lock()
@@ -865,8 +890,9 @@ class GraphServeEngine:
                 )
 
             def build_member(a: COOMatrix, rid: int) -> Graph:
+                # host stage only: nothing but assembly reads a member
                 with span("serve.plan.build", rid=rid):
-                    return build_graph(a, config=tcfg)
+                    return build_host_graph(a, config=tcfg)
 
             def build() -> BatchedGraph:
                 plans = [
@@ -875,10 +901,12 @@ class GraphServeEngine:
                     )
                     for k, a, r in zip(member_keys, adjs, batch)
                 ]
+                self.n_plan_leaf_fetches += _assembly_fetches(plans, with_edges)
                 with span("serve.plan.assemble", wave=w):
                     bg = assemble_batched_graph(
                         plans, T, bucket, with_edges=with_edges
                     )
+                self.n_plan_leaf_puts += len(jax.tree_util.tree_leaves(bg.graph))
                 if decision is not None:
                     bg = dataclasses.replace(
                         bg,
@@ -1063,6 +1091,8 @@ class GraphServeEngine:
             "plan_cache_entries": s.entries,
             "plan_cache_hit_rate": s.hit_rate,
             "plan_build_seconds": s.build_seconds,
+            "plan_leaf_puts": self.n_plan_leaf_puts,
+            "plan_leaf_fetches": self.n_plan_leaf_fetches,
             # autotune: per-regime resolved configs (key = histogram
             # signature x machine fingerprint) and per tracked graph the
             # config as of its last registration/anchor

@@ -3,9 +3,9 @@
 The paper builds SCV host-side ("statically generated from the COO format",
 §III-C) — a per-graph cost the serving path would otherwise repay on every
 request.  This module caches the prepared plan (the ``Graph`` bundle from
-``models/gnn.py``: SCV tiles + device arrays + permutation) keyed by a
-content hash of the COO adjacency, so hot graphs skip preprocessing
-entirely.
+``models/gnn.py``: SCV tiles + permutation, in numpy for the engine's
+member plans and on the device for its composites) keyed by a content
+hash of the COO adjacency, so hot graphs skip preprocessing entirely.
 
 Design:
 

@@ -41,7 +41,9 @@ chunk layout — array shapes, tile coordinates, schedule — is preserved
 exactly, so downstream jit traces keyed on leaf shapes survive.  For
 bucketed plans, a tile is re-bucketed **only** when its new chunk nnz
 crosses a `caps` ladder boundary; segments the delta never touches keep
-their device arrays by identity.
+their arrays by identity.  A patched plan or graph keeps its leaves where
+the input's were: device arrays stay device arrays, and a host-stage plan
+(numpy leaves, the serving engine's member plans) stays on the host.
 
 Requirements on the input (raising ``ValueError`` otherwise):
 
@@ -715,11 +717,27 @@ def _apply_plan_arrays(
     )
 
 
+def _place_like(ref, arrays) -> list:
+    """``arrays`` where ``ref`` lives: as they are when ``ref`` is a numpy
+    (host-stage) leaf, else put to the device."""
+    if isinstance(ref, np.ndarray):
+        return list(arrays)
+    import jax.numpy as jnp
+
+    return [jnp.asarray(a) for a in arrays]
+
+
+def _with_leaves(p: SCVPlan, leaves) -> SCVPlan:
+    """``p`` with new array leaves (``tree_flatten`` order), placed like
+    ``p``'s own."""
+    return SCVPlan.tree_unflatten(
+        p.tree_flatten()[1], _place_like(p.tile_row, leaves)
+    )
+
+
 def _apply_plan(
     p: SCVPlan, delta: DeltaBatch, source=None
 ) -> tuple[SCVPlan, _IdPlan]:
-    import jax.numpy as jnp
-
     if p.perm is None:
         raise ValueError(
             "apply_delta needs the plan's perm leaf; this plan was built "
@@ -735,13 +753,7 @@ def _apply_plan(
         source=source,
     )
     return (
-        dataclasses.replace(
-            p,
-            tile_row=jnp.asarray(tr2), tile_col=jnp.asarray(tc2),
-            rows=jnp.asarray(rs2), cols=jnp.asarray(cs2),
-            vals=jnp.asarray(vs2), nnz_in_tile=jnp.asarray(nz2),
-            perm=jnp.asarray(pm2),
-        ),
+        _with_leaves(p, (tr2, tc2, rs2, cs2, vs2, nz2, pm2)),
         idp,
     )
 
@@ -839,8 +851,6 @@ def _gather_bucketed(views: list[_SegView]) -> _Entries:
 def _apply_bucketed(
     bp: SCVBucketedPlan, delta: DeltaBatch, source=None
 ) -> tuple[SCVBucketedPlan, _IdPlan]:
-    import jax.numpy as jnp
-
     caps = bp.caps
     cap_build = caps[-1]
     T = bp.tile
@@ -927,32 +937,15 @@ def _apply_bucketed(
         missing = _coverage_tail(tile_row, nbr) if b == 0 \
             else np.zeros(0, np.int64)
         kd = len(missing)
-        out_segments.append(
-            dataclasses.replace(
-                s,
-                tile_row=jnp.asarray(
-                    np.concatenate([tile_row, missing.astype(tile_row.dtype)])
-                ),
-                tile_col=jnp.asarray(
-                    np.concatenate([tile_col, np.zeros(kd, tile_col.dtype)])
-                ),
-                rows=jnp.asarray(
-                    np.concatenate([rows, np.zeros((kd, cap_b), rows.dtype)])
-                ),
-                cols=jnp.asarray(
-                    np.concatenate([cols, np.zeros((kd, cap_b), cols.dtype)])
-                ),
-                vals=jnp.asarray(
-                    np.concatenate([vals, np.zeros((kd, cap_b), vals.dtype)])
-                ),
-                nnz_in_tile=jnp.asarray(
-                    np.concatenate([nz, np.zeros(kd, nz.dtype)])
-                ),
-                perm=jnp.asarray(
-                    np.concatenate([perm, np.full((kd, cap_b), -1, perm.dtype)])
-                ),
-            )
-        )
+        out_segments.append(_with_leaves(s, (
+            np.concatenate([tile_row, missing.astype(tile_row.dtype)]),
+            np.concatenate([tile_col, np.zeros(kd, tile_col.dtype)]),
+            np.concatenate([rows, np.zeros((kd, cap_b), rows.dtype)]),
+            np.concatenate([cols, np.zeros((kd, cap_b), cols.dtype)]),
+            np.concatenate([vals, np.zeros((kd, cap_b), vals.dtype)]),
+            np.concatenate([nz, np.zeros(kd, nz.dtype)]),
+            np.concatenate([perm, np.full((kd, cap_b), -1, perm.dtype)]),
+        )))
     return SCVBucketedPlan(tuple(out_segments)), p
 
 
@@ -960,8 +953,6 @@ def _apply_bucketed(
 # Graph patch (plan + COO edge arrays)
 # ---------------------------------------------------------------------------
 def _apply_graph(g, delta: DeltaBatch):
-    import jax.numpy as jnp
-
     # the Graph carries its own pre-delta edge arrays — use them as the
     # moved-survivor source so the plan patch never falls back to the
     # perm-scan
@@ -980,9 +971,11 @@ def _apply_graph(g, delta: DeltaBatch):
         r = np.asarray(g.rows)
         c = np.asarray(g.cols)
         w = np.asarray(g.vals)
-        rows = jnp.asarray(_fill_array(r, delta.ins_rows, idp))
-        cols = jnp.asarray(_fill_array(c, delta.ins_cols, idp))
-        vals = jnp.asarray(_fill_array(w, delta.ins_vals, idp))
+        rows, cols, vals = _place_like(g.rows, (
+            _fill_array(r, delta.ins_rows, idp),
+            _fill_array(c, delta.ins_cols, idp),
+            _fill_array(w, delta.ins_vals, idp),
+        ))
     return dataclasses.replace(g, plan=plan2, rows=rows, cols=cols, vals=vals)
 
 

@@ -7,12 +7,18 @@ import pytest
 
 from repro.core.formats import COOMatrix, block_diag_coo, coo_from_dense
 from repro.models.gnn import (
+    BatchedGraph,
     GNNConfig,
+    Graph,
+    batch_features,
     build_batched_graph,
     build_graph,
+    build_host_graph,
     gnn_forward,
     gnn_forward_batched,
+    gnn_forward_jit,
     init_gnn,
+    split_outputs,
 )
 from repro.serve.graph_engine import (
     GraphEngineConfig,
@@ -323,7 +329,7 @@ def test_engine_debug_validate_catches_corrupt_plan(rng, monkeypatch):
     import repro.serve.graph_engine as ge
     from repro.core.validate import PlanInvariantError
 
-    real_build = ge.build_graph
+    real_build = ge.build_host_graph
 
     def corrupt_build(*a, **k):
         g = real_build(*a, **k)
@@ -333,7 +339,7 @@ def test_engine_debug_validate_catches_corrupt_plan(rng, monkeypatch):
         segs = (_dc.replace(seg0, nnz_in_tile=nnz),) + g.plan.segments[1:]
         return _dc.replace(g, plan=_dc.replace(g.plan, segments=segs))
 
-    monkeypatch.setattr(ge, "build_graph", corrupt_build)
+    monkeypatch.setattr(ge, "build_host_graph", corrupt_build)
     eng, _, _ = _engine(debug_validate=True, max_retries=0)
     adj = _graphs([30], seed=34)[0]
     eng.submit(GraphRequest(rid=0, adj=adj, x=np.zeros((30, 8), np.float32),
@@ -745,3 +751,123 @@ def test_engine_mixed_model_kinds_batch_separately(rng):
     assert eng.metrics()["batches"] == 2  # one per kind
     for r in done:
         assert r.out.shape == (40, 4) and np.isfinite(r.out).all()
+
+
+# ---------------------------------------------------------------------------
+# member plans on the host, one put per composite
+# ---------------------------------------------------------------------------
+def _layout(ladder: bool) -> dict:
+    return {"bucket_caps": (8, 32, 128)} if ladder else {"backend_cap": 64}
+
+
+def _assert_leaves_equal(a, b):
+    la, ta = jax.tree_util.tree_flatten(a)
+    lb, tb = jax.tree_util.tree_flatten(b)
+    assert ta == tb
+    for x, y in zip(la, lb):
+        assert x.dtype == y.dtype and x.shape == y.shape
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("with_edges", [False, True], ids=["gcn", "gat"])
+@pytest.mark.parametrize("ladder", [True, False], ids=["ladder", "single_cap"])
+def test_host_members_assemble_to_the_device_composite(ladder, with_edges):
+    """Host-stage members (numpy leaves) and device-leaf members of the
+    same graphs assemble to the same composite, leaf for leaf; both
+    composites come out on the device."""
+    adjs = _graphs([70, 130, 50], seed=21)
+    host = [build_host_graph(a, tile=64, **_layout(ladder)) for a in adjs]
+    dev = [build_graph(a, tile=64, **_layout(ladder)) for a in adjs]
+    for h, d in zip(host, dev):
+        assert all(
+            isinstance(x, np.ndarray) for x in jax.tree_util.tree_leaves(h)
+        )
+        assert all(isinstance(x, jax.Array) for x in jax.tree_util.tree_leaves(d))
+        _assert_leaves_equal(h, d)  # build_graph is the host stage, put
+    bg_host = assemble_batched_graph(host, 64, 512, with_edges=with_edges)
+    bg_dev = assemble_batched_graph(dev, 64, 512, with_edges=with_edges)
+    _assert_leaves_equal(bg_host, bg_dev)
+    leaves = jax.tree_util.tree_leaves(bg_host.graph)
+    assert leaves and all(isinstance(x, jax.Array) for x in leaves)
+    assert (bg_host.graph.rows is not None) == with_edges
+
+
+def _device_path_outputs(eng, params, cfg, reqs, adjs):
+    """The wave served the way the engine did before member plans moved
+    to the host: device-leaf members through ``build_graph``, assembled
+    at the engine's padding bucket, one jitted forward."""
+    (bg,) = [
+        v for v in map(eng.plan_cache.peek, eng.plan_cache.keys)
+        if isinstance(v, BatchedGraph)
+    ]
+    members = [build_graph(a, tile=64, bucket_caps=eng.cfg.bucket_caps)
+               for a in adjs]
+    ref_bg = assemble_batched_graph(
+        members, 64, bg.graph.n_nodes, with_edges=cfg.kind == "gat"
+    )
+    _assert_leaves_equal(bg, ref_bg)
+    out = gnn_forward_jit(
+        params, cfg, ref_bg.graph, batch_features(ref_bg, [r.x for r in reqs])
+    )
+    return split_outputs(ref_bg, np.asarray(out)), bg
+
+
+@pytest.mark.parametrize("kind", ["gcn", "gat"])
+def test_engine_keeps_member_plans_on_host(kind, rng):
+    """A wave of distinct graphs: cached members hold numpy leaves, no
+    plan array comes back from the device, one composite's leaves go
+    out, and the outputs equal the device-member path's bit for bit."""
+    adjs = _graphs([40, 70, 30, 90, 50], seed=23)
+    xs = _features(rng, adjs, 8)
+    eng, params, cfg = _engine(kind)
+    reqs = [
+        eng.submit(GraphRequest(rid=i, adj=a, x=x, model=kind))
+        for i, (a, x) in enumerate(zip(adjs, xs))
+    ]
+    assert len(eng.run()) == len(adjs)
+    members = [
+        v for v in map(eng.plan_cache.peek, eng.plan_cache.keys)
+        if isinstance(v, Graph)
+    ]
+    assert len(members) == len(adjs)
+    for g in members:
+        assert all(
+            isinstance(x, np.ndarray) for x in jax.tree_util.tree_leaves(g)
+        )
+    ref, bg = _device_path_outputs(eng, params, cfg, reqs, adjs)
+    m = eng.metrics()
+    assert m["plan_leaf_fetches"] == 0
+    assert m["plan_leaf_puts"] == len(jax.tree_util.tree_leaves(bg.graph))
+    for r, o in zip(reqs, ref):
+        np.testing.assert_array_equal(r.out, o)
+
+
+def test_engine_update_patches_host_member(rng):
+    """A delta on a tracked graph patches its cached host member in
+    place of a rebuild; the next wave equals a fresh engine's build of
+    the post-delta graph, and the member stays on the host."""
+    from repro.stream import apply_coo
+
+    adj = _graphs([60], seed=25)[0]
+    x = rng.standard_normal((60, 8)).astype(np.float32)
+    eng, params, cfg = _engine()
+    eng.submit(GraphRequest(rid=0, adj=adj, x=x, model="gcn", graph_id="g0"))
+    eng.run()
+    d = _value_update(adj, [0, 3, 5], 4.5)
+    key = eng.update("g0", d)
+    assert eng.metrics()["plan_cache_revalidated"] == 1
+    patched = eng.plan_cache.peek(key)
+    assert all(
+        isinstance(v, np.ndarray) for v in jax.tree_util.tree_leaves(patched)
+    )
+    eng.submit(GraphRequest(rid=1, x=x, model="gcn", graph_id="g0"))
+    out = eng.run()[0].out
+
+    fresh, _, _ = _engine()
+    fresh.submit(GraphRequest(rid=0, adj=apply_coo(adj, d), x=x, model="gcn"))
+    np.testing.assert_array_equal(out, fresh.run()[0].out)
+    _assert_leaves_equal(
+        patched, build_host_graph(apply_coo(adj, d), tile=64,
+                                  bucket_caps=eng.cfg.bucket_caps),
+    )
+    assert eng.metrics()["plan_leaf_fetches"] == 0

@@ -41,11 +41,12 @@ Rules (all stdlib ``ast`` + ``tokenize``; no third-party dependency):
   prefetched data (the PR 2 breakage this rule fossilizes).
 * **SCV006 stream-no-rebuild** — no full-rebuild entry points
   (``coo_to_scv_tiles`` / ``plan_from_tiles`` /
-  ``plan_from_tiles_bucketed`` / ``build_graph``) called inside
-  ``src/repro/stream/``.  The delta package exists to *patch* plans in
-  sub-rebuild time; a rebuild call hiding inside it silently converts
-  the O(delta) contract back into the O(nnz) path it replaces.  Tests
-  and benchmarks rebuild freely — the rule is scoped to the package.
+  ``plan_from_tiles_bucketed`` / ``build_graph`` and their host stages)
+  called inside ``src/repro/stream/``.  The delta package exists to
+  *patch* plans in sub-rebuild time; a rebuild call hiding inside it
+  silently converts the O(delta) contract back into the O(nnz) path it
+  replaces.  Tests and benchmarks rebuild freely — the rule is scoped
+  to the package.
 * **SCV007 queue-ownership** — no direct ``self.queue`` mutation inside
   ``src/repro/serve/`` outside the scheduler/intake module
   (``serve/scheduler.py``).  The intake queue is the single place where
@@ -99,7 +100,8 @@ QUEUE_MUTATORS = frozenset(
 #: O(delta) -> O(nnz) regression.
 REBUILD_ENTRY_POINTS = frozenset(
     {"coo_to_scv_tiles", "plan_from_tiles", "plan_from_tiles_bucketed",
-     "build_graph"}
+     "build_graph", "host_plan_from_tiles", "host_plan_from_tiles_bucketed",
+     "build_host_graph"}
 )
 
 #: SCVPlan / SCVTiles leaf parameter names (SCV003).
