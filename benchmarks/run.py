@@ -1,5 +1,5 @@
-"""Benchmark harness: one function per paper table/figure (+ kernel
-microbench and the dry-run roofline table when artifacts exist).
+"""Benchmark harness: one function per paper table/figure (+ the dry-run
+roofline table when artifacts exist).
 
 Prints ``name,us_per_call,derived`` CSV rows (simulated cycles at 1 GHz ->
 us) and writes the full row dumps to results/benchmarks.json.
@@ -10,43 +10,6 @@ import json
 import os
 import sys
 import time
-
-
-def _kernel_microbench():
-    """Wall-clock of the SCV aggregation backends on CPU (relative numbers
-    only — the TPU path is characterized by the dry-run roofline)."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from repro.core import coo_to_scv_tiles, coo_to_csr
-    from repro.core.aggregate import (aggregate, aggregate_scv_tiles,
-                                      scv_device_arrays)
-    from repro.simul.datasets import gcn_normalize, powerlaw_graph
-
-    adj = gcn_normalize(powerlaw_graph(20_000, 100_000, seed=0))
-    f = 128
-    z = jnp.asarray(np.random.default_rng(0).standard_normal(
-        (adj.shape[1], f)).astype(np.float32))
-    rows = []
-    tiles = coo_to_scv_tiles(adj, 64)
-    csr = coo_to_csr(adj)
-
-    def timeit(fn, n=5):
-        fn().block_until_ready()
-        t0 = time.time()
-        for _ in range(n):
-            out = fn()
-        out.block_until_ready()
-        return (time.time() - t0) / n * 1e6
-
-    t_scv = timeit(lambda: aggregate_scv_tiles(tiles, z, backend="jnp"))
-    t_csr = timeit(lambda: aggregate(csr, z))
-    rows.append({"figure": "kernel", "name": "scv_jnp_cpu", "us_per_call": t_scv,
-                 "derived": f"csr/scv={t_csr/t_scv:.2f}"})
-    rows.append({"figure": "kernel", "name": "csr_segsum_cpu", "us_per_call": t_csr,
-                 "derived": ""})
-    return rows
 
 
 def main() -> None:
@@ -77,18 +40,6 @@ def main() -> None:
                 print(f"{name}:{r['dataset']}:{':'.join(key)},{us},{metric:.3f}"
                       if metric != "" else f"{name}:{r['dataset']},{us},")
         print(f"# {name} done in {dt:.1f}s ({len(rows)} rows)", flush=True)
-
-    if only is None or only == "kernel":
-        for r in _kernel_microbench():
-            print(f"{r['figure']}:{r['name']},{r['us_per_call']:.1f},{r['derived']}")
-            all_rows.append(r)
-
-    if only is None or only == "kernel_roofline":
-        from benchmarks.kernel_roofline import main as kr_main
-
-        print("# SCV kernel roofline / hybrid analysis (EXPERIMENTS §Perf cell K)")
-        kr_rows = kr_main()
-        all_rows.extend({"figure": "kernel_roofline", **r} for r in kr_rows)
 
     # roofline table from dry-run artifacts, if present
     path = "results/dryrun_single_pod.json"

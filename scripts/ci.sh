@@ -1,39 +1,14 @@
 #!/usr/bin/env bash
-# Fast CI tier: the whole suite minus the multi-minute `slow`-marked
-# modules — a seconds-scale default loop.  Pass extra pytest args through,
-# e.g. `scripts/ci.sh -k serve`.  The full tier-1 command (ROADMAP.md)
-# remains `PYTHONPATH=src python -m pytest -x -q`.
+# Fast CI tier: lint, then the whole suite minus the multi-minute
+# `slow`-marked modules.  Pass extra pytest args through, e.g.
+# `scripts/ci.sh -k serve`.  The full tier-1 command (ROADMAP.md) remains
+# `PYTHONPATH=src python -m pytest -x -q`.
 #
-# Two PR gates always hold:
-#   * the jitted-forward equivalence checks (whole GNN forward under one
-#     jax.jit must match the unjitted path for all model kinds) — part of
-#     the default suite; re-run explicitly only when "$@" filters might
-#     have deselected them, and
-#   * benchmarks/preprocess_bench.py (vectorized SCV tile construction
-#     >= 5x the scalar loop on a 1M-edge graph; emits BENCH_preprocess.json),
-#   * benchmarks/kernel_bench.py (vectorized/bucketed Pallas kernel body
-#     >= 3x the scalar-loop kernel at 1M edges on a power-law graph,
-#     interpret mode, bit-exact vs the jnp reference; emits
-#     BENCH_kernel.json),
-#   * benchmarks/dist_bench.py (executor-placed bucketed plan on a forced
-#     8-host-device mesh: tile/feature/2-D sharding bit-exact vs the
-#     single-device bucketed path, balanced spans, bounded overhead;
-#     emits BENCH_dist.json),
-#   * benchmarks/serve_bench.py (engine >= naive loop, cache hits, the
-#     bucketed-vs-single-cap A/B plus the ladder-depth sweep that gates
-#     the DEFAULT_LADDER default against the measured winner, and the
-#     Poisson open-loop sync-vs-async A/B: async p99 <= sync p99 at equal
-#     offered load, async holds >= 0.9x sync graphs/s at saturation,
-#     exact-output parity vs the unbatched forward; emits
-#     BENCH_serve.json with the open-loop percentiles),
-#   * benchmarks/stream_bench.py (small-delta stream.apply_delta >= 10x a
-#     full coo_to_scv_tiles rebuild at 1M edges, byte-identical to the
-#     rebuild; engine updates land as plan-cache revalidations, never
-#     full misses; emits BENCH_stream.json),
-#   * benchmarks/autotune_bench.py (simulator-pruned config search never
-#     loses to the measured default control on either regime, strictly
-#     beats it on at least one, and re-resolves both from the on-disk
-#     cache with zero new searches; emits BENCH_autotune.json).
+# CI checks counts, bit-exactness and retraces; it times nothing.  Speed
+# is judged on the chip by `chipbench/` (BENCHMARK.json).  The jitted-
+# forward equivalence checks (the whole GNN forward under one jax.jit
+# must match the unjitted path for all model kinds) always run: when
+# "$@" filters might have deselected them they run again explicitly.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
@@ -42,9 +17,3 @@ python -m pytest -q -m "not slow" "$@"
 if [ "$#" -gt 0 ]; then
   python -m pytest -q tests/test_scv_plan.py -k "jit" --no-header
 fi
-python benchmarks/preprocess_bench.py
-python benchmarks/kernel_bench.py
-python benchmarks/dist_bench.py
-python benchmarks/serve_bench.py
-python benchmarks/stream_bench.py
-python benchmarks/autotune_bench.py

@@ -5,8 +5,8 @@
 # hygiene, fori_loop unroll).  New violations fail the run; pre-existing
 # ones live in tools/scvlint/baseline.txt.
 #
-# Run directly (`scripts/lint.sh`) or via scripts/ci.sh, which gates on
-# it before the pytest tier.
+# Run directly (`scripts/lint.sh`) or via scripts/ci.sh, which runs it
+# before the pytest tier.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 

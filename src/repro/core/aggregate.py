@@ -244,7 +244,7 @@ def aggregate_hybrid(
     t: SCVTiles, z: jnp.ndarray, *, backend: str = "jnp", **kw
 ) -> jnp.ndarray:
     """Beyond-paper hybrid: MXU-densified tiles + SCV gather tiles
-    (DESIGN.md §2; measured in benchmarks/kernel_roofline.py)."""
+    (DESIGN.md §2)."""
     from repro.core.scv import split_hybrid
 
     sparse, dense = split_hybrid(t)

@@ -162,12 +162,11 @@ def placement_bytes(
     coverage dummies included; ``repro.tune.plan_launched_slots`` of a
     built plan, or ``core.scv.launched_slots`` from a histogram).  When
     given, the plan triple is priced at slots instead of logical nnz —
-    the shipped arrays really are slot-shaped, and BENCH_dist measured
-    the nnz-priced model 1.11-3.79x optimistic against placed plans.
-    This is the same pricing the autotuner's stage-1 model uses
-    (``repro.tune.cost``), so placement and plan tuning charge padding
-    identically.  Omitted, the legacy nnz pricing applies (callers that
-    predate any plan, e.g. the serving admission estimate).
+    the shipped arrays really are slot-shaped.  This is the same pricing
+    the autotuner's model uses (``repro.tune.cost``), so placement and
+    plan tuning charge padding identically.  Omitted, the legacy nnz
+    pricing applies (callers that predate any plan, e.g. the serving
+    admission estimate).
     """
     if machine is None:
         from repro.simul.machine import MachineConfig

@@ -35,7 +35,8 @@ Construction is host-side preprocessing ("statically generated from the COO
 format ... nearly equivalent to creating a CSR or CSC matrix" — §III-C);
 ``coo_to_scv_tiles`` emits tiles with vectorized numpy scatter, so the cost
 really is a couple of sorts plus O(nnz) array ops even at million-edge
-scale (``benchmarks/preprocess_bench.py`` gates this).
+scale (``tests/test_scv_plan.py`` holds it byte-identical to the scalar
+per-tile loop).
 """
 from __future__ import annotations
 
@@ -55,8 +56,7 @@ ZMORTON = "zmorton"
 # ---------------------------------------------------------------------------
 # Kernel-model constants (DESIGN.md §2) — the single source of truth shared
 # by the Pallas kernel (`kernels/scv_spmm`), the hybrid split below, and the
-# roofline model (`benchmarks/kernel_roofline.py` imports these so the model
-# and the implementation cannot drift).
+# tuner's cost model (`tune/`).
 # ---------------------------------------------------------------------------
 #: VPU FMA-lane rate over MXU MAC rate (v5e: 8x128 lanes vs 128x128 MACs).
 MXU_VPU_RATIO = 1.0 / 16.0
@@ -72,13 +72,11 @@ MIN_BUCKET_CAP = 8
 DEFAULT_TILE = 64
 #: Default single-bucket per-tile capacity when bucketing is disabled.
 DEFAULT_CAP = 64
-#: Default serving capacity ladder — the measured ladder A/B winner on the
-#: sparse 131k-node pool (serve_bench ``ladder_ab``; 3-deep won the PR 10
-#: re-run and serve_bench now *fails* if a recorded winner beats the
-#: default past the ladder slack band, so this constant tracks the
-#: measurement instead of drifting stale).  Per-regime overrides come
-#: from ``repro.tune.TunedConfig``; scvlint SCV002 rejects re-declared
-#: tile/cap/ladder literals outside this module and ``tune/config.py``.
+#: Default serving capacity ladder: a fixed constant that every benchmark
+#: cell serves.  No chip measurement has compared ladders.  Per-regime
+#: overrides come from ``repro.tune.TunedConfig``; scvlint SCV002 rejects
+#: re-declared tile/cap/ladder literals outside this module and
+#: ``tune/config.py``.
 DEFAULT_LADDER = (8, 32, 128)
 
 
@@ -157,8 +155,8 @@ def launched_slots(
     already covered by a first-segment tile need no dummy.
 
     This is the number the byte model must price (``3 * slots * B`` for
-    the rows/cols/vals triple), not logical nnz: BENCH_dist measured the
-    nnz-priced model 1.11-3.79x optimistic against placed plans.
+    the rows/cols/vals triple), not logical nnz: the shipped arrays are
+    slot-shaped, padding included.
     """
     caps_arr = np.asarray(sorted(int(c) for c in caps), dtype=np.int64)
     if caps_arr.size == 0:
@@ -430,7 +428,7 @@ def coo_to_scv_tiles(
     entry ``start[tile(chunk)] + chunk_local * cap + s`` — no Python loop
     over tiles, so plan construction stays a few sorts + O(nnz) array ops
     at million-edge scale (``_coo_to_scv_tiles_loop`` keeps the scalar
-    emitter as the equivalence/benchmark reference).
+    emitter as the tests' equivalence reference).
     """
     T = int(tile)
     utrow, utcol, start, counts, sched, eorder, lrow_s, lcol_s, vals_s = _tile_sort(
@@ -499,8 +497,7 @@ def _coo_to_scv_tiles_loop(
     order: str = ZMORTON,
 ) -> SCVTiles:
     """Scalar per-tile emission loop — the pre-vectorization construction,
-    kept as the byte-identical reference for tests and
-    ``benchmarks/preprocess_bench.py``."""
+    kept as the byte-identical reference for tests."""
     T = int(tile)
     utrow, utcol, start, counts, sched, eorder, lrow_s, lcol_s, vals_s = _tile_sort(
         a, T, order

@@ -28,8 +28,8 @@ test into a reusable analysis any entry point gets for free:
   bucket traced more than ``max_retraces_per_bucket`` times.
 
 Everything reports into a machine-readable :class:`TraceReport`
-mirroring ``core.validate.ValidationReport`` — `scripts/ci.sh` gates on
-both through the tests in ``tests/test_tracecheck.py``.
+mirroring ``core.validate.ValidationReport``; ``tests/test_tracecheck.py``
+checks both.
 """
 from __future__ import annotations
 

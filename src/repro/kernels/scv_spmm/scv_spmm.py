@@ -2,7 +2,7 @@
 
 Mapping of the paper's mechanisms onto Pallas/TPU:
 
-* Three kernel bodies share the launch name ``scv_spmm``; ``body="auto"``
+* Two kernel bodies share the launch name ``scv_spmm``; ``body="auto"``
   (the default) picks one per segment from its static ``(cap, tile)``
   and tile count (``resolve_body``, ``core.scv.uses_row_gather``).
 
@@ -31,19 +31,15 @@ Mapping of the paper's mechanisms onto Pallas/TPU:
   MXU matmuls: ``out += S @ (Gᵀ Z)``.  Tiles whose prefetched nnz
   exceeds ``dense_tile_threshold(T)`` are instead densified in-kernel
   (``D += S Gᵀ``, a ``(T, T)`` block) and hit the MXU as one plain
-  ``out += D @ Z`` matmul — the hybrid selection rule
-  ``benchmarks/kernel_roofline.py`` models, implemented.  Coverage-dummy
-  tiles (nnz == 0) skip all compute via ``pl.when``.
-
-* ``body="scalar"`` — the pre-vectorization per-entry FMA loop, kept as
-  the measured baseline for ``benchmarks/kernel_bench.py``.
+  ``out += D @ Z`` matmul (the MXU/VPU crossover of DESIGN.md §2).
+  Coverage-dummy tiles (nnz == 0) skip all compute via ``pl.when``.
 
 * Every body keeps one PS strip per block-row in VMEM across all the
   block-row's tiles and writes it to HBM once: the tile schedule keeps
   block-rows contiguous (``SCVTiles`` invariant) — §III-B's "fetched PS
-  rows are reused multiple times before being evicted".  The vector and
-  scalar bodies get this from an output BlockSpec that revisits the
-  strip; the row-gather body manages the strip itself.
+  rows are reused multiple times before being evicted".  The vector
+  body gets this from an output BlockSpec that revisits the strip; the
+  row-gather body manages the strip itself.
 
 * Padding entries carry val == 0 and are additionally skipped by bounding
   the chunk/entry loop with the prefetched per-tile nnz.
@@ -77,50 +73,6 @@ from repro.core.scv import (
 #: (same error and time as HIGHEST there); HIGHEST states the contract
 #: instead of leaning on that default.
 ONEHOT_PRECISION = jax.lax.Precision.HIGHEST
-
-
-def _kernel_scalar(
-    # scalar-prefetch operands
-    tile_row_ref,  # i32[nt]
-    tile_col_ref,  # i32[nt]  (steers z BlockSpec; unused in body)
-    nnz_ref,  # i32[nt]
-    # array operands
-    rows_ref,  # i32[1, cap]   (SMEM) local row of each entry
-    cols_ref,  # i32[1, cap]   (SMEM) local col of each entry
-    vals_ref,  # f32[1, cap]   (SMEM) value of each entry
-    z_ref,  # [T, Fb]       (VMEM) combined-feature block
-    *refs,  # (out_ref,) or (acc_ref, out_ref) in accumulate mode
-):
-    acc_ref, out_ref = refs if len(refs) == 2 else (None, refs[0])
-    t = pl.program_id(1)
-
-    # Fresh PS strip?  (first tile overall, or block-row changed.)
-    prev = jnp.maximum(t - 1, 0)
-    new_strip = jnp.logical_or(t == 0, tile_row_ref[t] != tile_row_ref[prev])
-
-    @pl.when(new_strip)
-    def _init():
-        # accumulate mode: seed the strip from the chained accumulator
-        # (the prior launch's output, aliased into this launch's buffer)
-        # instead of zero — unvisited strips pass through untouched.
-        if acc_ref is None:
-            out_ref[...] = jnp.zeros_like(out_ref)
-        else:
-            out_ref[...] = acc_ref[...]
-
-    nnz = nnz_ref[t]
-
-    def body(i, _):
-        r = rows_ref[0, i]
-        c = cols_ref[0, i]
-        v = vals_ref[0, i].astype(jnp.float32)
-        zrow = z_ref[pl.ds(c, 1), :].astype(jnp.float32)
-        out_ref[pl.ds(r, 1), :] += v * zrow
-        return 0
-
-    # No `unroll=`: jax raises ValueError for an unrolled fori_loop with
-    # traced bounds, and nnz is prefetched data.
-    jax.lax.fori_loop(0, nnz, body, 0)
 
 
 def _kernel_vector(
@@ -521,10 +473,13 @@ def resolve_body(body: str, cap: int, tile: int, n_tiles: int) -> str:
     """The kernel body a segment of ``n_tiles`` tiles runs: ``"auto"`` picks
     the row-gather body for large segments whose capacity is small against
     the tile (``core.scv.uses_row_gather``) and the vector body otherwise;
-    any other name is taken as given."""
-    if body != "auto":
-        return body
-    return "rows" if uses_row_gather(cap, tile, n_tiles) else "vector"
+    ``"rows"`` and ``"vector"`` are taken as given, any other name raises
+    ``ValueError``."""
+    if body == "auto":
+        return "rows" if uses_row_gather(cap, tile, n_tiles) else "vector"
+    if body not in ("rows", "vector"):
+        raise ValueError(f"unknown kernel body {body!r}")
+    return body
 
 
 @functools.partial(
@@ -555,8 +510,8 @@ def scv_spmm_pallas(
     """One SCV SpMM launch.
 
     ``body`` names the kernel body (``resolve_body``): ``"auto"`` (the
-    default) picks it from the static ``(cap, tile)``; ``"rows"``,
-    ``"vector"`` and ``"scalar"`` force one.
+    default) picks it from the static ``(cap, tile)``; ``"rows"`` and
+    ``"vector"`` force one.
 
     With ``acc`` (accumulate mode) the launch computes ``acc + Â Z``
     instead of ``Â Z``: the accumulator is aliased onto the output buffer
@@ -582,37 +537,27 @@ def scv_spmm_pallas(
             tile=T, n_rows=n_rows, feature_block=Fb, interpret=interpret,
             chunk=chunk,
         )
-    if body == "vector":
-        # chunk the entry arrays evenly: pad cap up to a multiple of the
-        # chunk size (static shapes; the pad slots are structural zeros)
-        C = min(int(chunk), max(cap, 1))
-        if cap % C:
-            pad = C - cap % C
-            rows = jnp.pad(rows, ((0, 0), (0, pad)))
-            cols = jnp.pad(cols, ((0, 0), (0, pad)))
-            vals = jnp.pad(vals, ((0, 0), (0, pad)))
-            cap += pad
-        thr = dense_tile_threshold(T) if dense_threshold is None else int(dense_threshold)
-        kernel = functools.partial(
-            _kernel_vector, tile=T, chunk=C, dense_threshold=thr
-        )
-        # entry arrays feed vector compute (iota compares + matmuls), so
-        # they live in VMEM as [nt, cap // C, C]: one tile's (cap // C, C)
-        # block spans the array's last two dims (the TPU tiling accepts it
-        # at any C), and chunk k is a sublane row of it
-        rows, cols, vals = (a.reshape(nt, cap // C, C) for a in (rows, cols, vals))
-        entry_spec = pl.BlockSpec(
-            (None, cap // C, C), lambda f, t, tr, tc, nz: (t, 0, 0)
-        )
-    elif body == "scalar":
-        kernel = _kernel_scalar
-        # interpret-mode bench baseline only: a (1, cap) block is below
-        # the TPU tiling minimum, so this body does not lower for the chip
-        entry_spec = pl.BlockSpec(
-            (1, cap), lambda f, t, tr, tc, nz: (t, 0), memory_space=pltpu.SMEM
-        )
-    else:
-        raise ValueError(f"unknown kernel body {body!r}")
+    # chunk the entry arrays evenly: pad cap up to a multiple of the
+    # chunk size (static shapes; the pad slots are structural zeros)
+    C = min(int(chunk), max(cap, 1))
+    if cap % C:
+        pad = C - cap % C
+        rows = jnp.pad(rows, ((0, 0), (0, pad)))
+        cols = jnp.pad(cols, ((0, 0), (0, pad)))
+        vals = jnp.pad(vals, ((0, 0), (0, pad)))
+        cap += pad
+    thr = dense_tile_threshold(T) if dense_threshold is None else int(dense_threshold)
+    kernel = functools.partial(
+        _kernel_vector, tile=T, chunk=C, dense_threshold=thr
+    )
+    # entry arrays feed vector compute (iota compares + matmuls), so
+    # they live in VMEM as [nt, cap // C, C]: one tile's (cap // C, C)
+    # block spans the array's last two dims (the TPU tiling accepts it
+    # at any C), and chunk k is a sublane row of it
+    rows, cols, vals = (a.reshape(nt, cap // C, C) for a in (rows, cols, vals))
+    entry_spec = pl.BlockSpec(
+        (None, cap // C, C), lambda f, t, tr, tc, nz: (t, 0, 0)
+    )
 
     grid = (f_p // Fb, nt)  # feature blocks outer, tiles inner
 

@@ -1,7 +1,7 @@
 """Graph serving launcher: the async GNN engine under open-loop load.
 
     PYTHONPATH=src python -m repro.launch.graph_serve \
-        --mode async --rate 150 --requests 200 --deadline-ms 0
+        --rate 150 --requests 200 --deadline-ms 0
 
 The engine aggregates through the compiled SCV kernel, so this runs on a
 TPU.
@@ -10,22 +10,14 @@ Stands the continuously-batched :class:`GraphServeEngine` (scheduler
 loop, mid-flight wave coalescing, deadline-aware admission) behind a
 **Poisson open-loop** request generator: arrivals follow an exponential
 inter-arrival clock that does *not* wait for completions, so queueing
-delay is measured instead of hidden — the closed-loop ``run()`` benches
-report throughput but can never see the latency a bursty workload pays
-(``--mode sync`` runs the same workload through a thread that drains
-synchronous waves, the degenerate baseline).
-
-The module is import-friendly on purpose: ``benchmarks/serve_bench.py``
-drives :func:`run_open_loop` with both modes at equal offered load for
-the CI latency gates, and this CLI is the human-facing surface over the
-same driver.
+delay is measured instead of hidden.  The engine's own ``start()`` /
+``stop()`` loop serves the requests.
 """
 from __future__ import annotations
 
 import argparse
 import os
 import pathlib
-import threading
 import time
 from typing import Optional
 
@@ -60,7 +52,7 @@ def enable_compile_cache() -> str:
 
 
 #: Hot-graph pool sizes for the default workload — the sparse power-law
-#: serving regime the capacity ladder targets (mirrors serve_bench).
+#: serving regime the capacity ladder targets.
 DEFAULT_POOL_SIZES = (600, 900, 1200, 1500, 2000, 2500)
 
 
@@ -105,52 +97,10 @@ def poisson_arrivals(
     return np.cumsum(rng.exponential(1.0 / rate_hz, size=n))
 
 
-class SyncWaveServer:
-    """The baseline serving shape: one thread draining the intake queue in
-    synchronous waves (``engine.run()``) — no mid-flight coalescing, no
-    dispatch/materialize overlap.  Producers still submit through the
-    thread-safe intake, so the sync and async modes see the identical
-    open-loop arrival process."""
-
-    def __init__(self, engine: GraphServeEngine):
-        self.engine = engine
-        self._running = False
-        self._thread: Optional[threading.Thread] = None
-
-    def start(self) -> None:
-        self._running = True
-        self._thread = threading.Thread(
-            target=self._loop, name="graph-serve-sync-waves", daemon=True
-        )
-        self._thread.start()
-
-    def stop(self, timeout: Optional[float] = None) -> None:
-        """Stop after draining everything queued (mirrors engine.stop())."""
-        self._running = False
-        self.engine.scheduler.queue.notify_all()
-        if self._thread is not None:
-            self._thread.join(timeout)
-            self._thread = None
-
-    def _loop(self) -> None:
-        eng = self.engine
-        while True:
-            if eng.scheduler.queue.depth():
-                try:
-                    eng.run()
-                except Exception:
-                    continue  # failure isolation already requeued/ejected
-            elif self._running:
-                eng.scheduler.queue.wait_for_work(timeout=0.01)
-            else:
-                return
-
-
 def run_open_loop(
     engine: GraphServeEngine,
     requests: list[GraphRequest],
     arrivals: np.ndarray,
-    mode: str = "async",
     result_timeout_s: float = 120.0,
 ) -> dict:
     """Drive ``requests`` at their Poisson ``arrivals`` offsets and block
@@ -163,14 +113,7 @@ def run_open_loop(
     the span from first arrival to last completion, and shed/reject
     counts.
     """
-    if mode not in ("async", "sync"):
-        raise ValueError(f"mode must be 'async' or 'sync', got {mode!r}")
-    server = None
-    if mode == "async":
-        engine.start()
-    else:
-        server = SyncWaveServer(engine)
-        server.start()
+    engine.start()
     submitted: list[GraphRequest] = []
     n_rejected = n_overloaded = 0
     t0 = time.perf_counter()
@@ -194,15 +137,11 @@ def run_open_loop(
                 )
         elapsed = time.perf_counter() - t0
     finally:
-        if mode == "async":
-            engine.stop(timeout=30.0)
-        else:
-            server.stop(timeout=30.0)
+        engine.stop(timeout=30.0)
     done = [r for r in submitted if r.done]
     shed = [r for r in submitted if not r.done]
     lats = np.array([r.latency_s for r in done], np.float64)
     return {
-        "mode": mode,
         "offered": len(requests),
         "completed": len(done),
         "shed": len(shed),
@@ -213,7 +152,6 @@ def run_open_loop(
         "p50_ms": float(np.percentile(lats, 50) * 1e3) if lats.size else None,
         "p99_ms": float(np.percentile(lats, 99) * 1e3) if lats.size else None,
         "mean_ms": float(lats.mean() * 1e3) if lats.size else None,
-        "outputs": {r.rid: r.out for r in done},
     }
 
 
@@ -242,7 +180,6 @@ def main(argv=None):
     ap = argparse.ArgumentParser(
         description="Async graph serving under Poisson open-loop load."
     )
-    ap.add_argument("--mode", choices=["async", "sync"], default="async")
     ap.add_argument("--rate", type=float, default=100.0,
                     help="offered load, requests/second")
     ap.add_argument("--requests", type=int, default=200)
@@ -272,10 +209,10 @@ def main(argv=None):
         warm.submit(r)
     warm.run()
 
-    stats = run_open_loop(engine, requests, arrivals, mode=args.mode)
+    stats = run_open_loop(engine, requests, arrivals)
     m = engine.metrics()
     print(
-        f"{args.mode}: {stats['completed']}/{stats['offered']} completed at "
+        f"{stats['completed']}/{stats['offered']} completed at "
         f"{stats['graphs_per_s']:.1f} graphs/s (offered {args.rate:.1f}/s)"
     )
     print(

@@ -166,17 +166,13 @@ class GraphEngineConfig:
     cap: int = DEFAULT_CAP  # per-tile entry capacity when bucket_caps is off
     # nnz-bucketed plans: a fixed ascending capacity ladder shared by every
     # member plan (so composites fuse segment-by-segment and jit traces are
-    # shared across batches).  ON by default — the serve_bench A/B
-    # (BENCH_serve.json) gates bucketed >= single-cap throughput AND the
-    # default ladder >= the measured ladder-depth winner.  With
-    # accumulator-chained launches coverage dummies exist once per plan,
-    # so ladder depth no longer pays a per-segment dummy set — the
-    # remaining depth cost is one launch (one jnp pass on the serving
-    # backend) per extra bucket; the 3-deep ladder won the interleaved
-    # sweep on the sparse serving pool (ladder_ab in BENCH_serve.json;
-    # 2/4-deep within ~5%).  Empty tuple selects the legacy single-cap plans
-    # (``cap``); when the ladder is set it supersedes ``cap`` (heavy
-    # tiles chain-split at ``bucket_caps[-1]``).
+    # shared across batches).  ON by default: ``DEFAULT_LADDER`` is a fixed
+    # constant that every benchmark cell serves; no chip measurement has
+    # compared ladders.  With accumulator-chained launches coverage
+    # dummies exist once per plan, so each extra bucket costs one launch.
+    # Empty tuple selects the legacy single-cap plans (``cap``); when the
+    # ladder is set it supersedes ``cap`` (heavy tiles chain-split at
+    # ``bucket_caps[-1]``).
     bucket_caps: tuple[int, ...] = DEFAULT_LADDER
     # autotuned per-regime plan configuration (repro.tune): when on, each
     # distinct graph regime (quantized tile-nnz histogram x machine
@@ -186,12 +182,10 @@ class GraphEngineConfig:
     # config (composite members must share tile and ladder), member and
     # composite cache keys carry the resolved layout, and ``metrics()``
     # reports every resolved config.  Resolution on a store hit costs one
-    # O(nnz) histogram per request per wave; a miss runs the stage-1
-    # simulator sweep (plus measured calibration when
-    # ``autotune_calibrate`` is set — leave that to offline benches).
+    # O(nnz) histogram per request per wave; a miss runs the simulator
+    # sweep.
     autotune: bool = False
     autotune_store: Optional[str] = None  # TuneStore path (None = in-memory)
-    autotune_calibrate: bool = False
     node_buckets: tuple[int, ...] = (256, 512, 1024, 2048, 4096)
     cache_entries: int = 256
     # bounds host-held member plans plus device-held composites together
@@ -614,10 +608,7 @@ class GraphServeEngine:
         if cfg.autotune:
             from repro.tune import Autotuner, TuneStore
 
-            self.tuner = Autotuner(
-                store=TuneStore(cfg.autotune_store),
-                calibrate=cfg.autotune_calibrate,
-            )
+            self.tuner = Autotuner(store=TuneStore(cfg.autotune_store))
 
     @property
     def queue(self) -> list[GraphRequest]:

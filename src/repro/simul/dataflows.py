@@ -110,9 +110,8 @@ def run_scv_bucketed(
     bucketed capacity slots instead of logical nnz.
 
     The device plan ships three 32-bit arrays (rows/cols/vals) per
-    capacity slot, padding included — BENCH_dist measured the nnz-priced
-    model 1.11-3.79x optimistic against placed plans — so ``bytes_a``
-    becomes ``3 * slots * E`` per feature pass, with ``slots`` from
+    capacity slot, padding included — so ``bytes_a`` becomes
+    ``3 * slots * E`` per feature pass, with ``slots`` from
     :func:`core.scv.launched_slots` (chain-split at the top cap, remainder
     in the smallest fitting cap, first-segment coverage dummies).  Compute
     cycles and the Z/PS traffic terms are unchanged: padding slots are

@@ -2,11 +2,10 @@
 
 SCV-GNN's advantage is a *preprocessed* plan — and its liability is that
 any edge change used to throw the plan away (`coo_to_scv_tiles` is
-O(nnz); ~0.5 s at 1M edges per BENCH_preprocess.json).  This module makes
-mutation first-class: a :class:`DeltaBatch` of edge inserts/removes and
-:func:`apply_delta`, which patches `SCVTiles` / `SCVPlan` /
-`SCVBucketedPlan` / `Graph` by splicing only the Z-Morton tiles the delta
-touches.
+O(nnz)).  This module makes mutation first-class: a :class:`DeltaBatch`
+of edge inserts/removes and :func:`apply_delta`, which patches
+`SCVTiles` / `SCVPlan` / `SCVBucketedPlan` / `Graph` by splicing only
+the Z-Morton tiles the delta touches.
 
 The contract that anchors correctness (tested in
 ``tests/test_plan_roundtrip.py`` / ``tests/test_stream.py``): the patched
@@ -27,9 +26,8 @@ chosen to minimize churn (**hole-filling**, see :class:`_IdPlan`):
 
 So the only entries whose source id changes are the ≤ ``n_remove`` moved
 tail survivors — a patch rewrites the tiles holding delta coordinates or
-moved survivors and **no** O(nnz) pass over the perm arrays ever happens
-(the property the update-vs-rebuild gate in ``benchmarks/stream_bench.py``
-rests on).  When removals outnumber inserts the moved survivors must be
+moved survivors and **no** O(nnz) pass over the perm arrays ever happens.
+When removals outnumber inserts the moved survivors must be
 located: ``apply_delta(..., source=<pre-delta COO>)`` finds their tiles by
 coordinate arithmetic (the ``Graph`` layer uses its own edge arrays);
 without a source the perm leaves are scanned once, blockwise.

@@ -4,12 +4,12 @@ Public surface:
 
 * :class:`TunedConfig` — frozen (T, C, ratio, ladder) record; the only
   sanctioned carrier of tile/cap/chunk values outside ``core/scv.py``.
-* :class:`Autotuner` — two-stage search (analytic prune, measured
-  calibration) with an on-disk :class:`TuneStore` cache.
+* :class:`Autotuner` — simulator-scored search with an on-disk
+  :class:`TuneStore` cache.
 * ``histogram_signature`` / ``machine_fingerprint`` / ``cache_key`` — the
   regime-keyed cache scheme.
 """
-from repro.tune.autotuner import Autotuner, ScoredCandidate, TuneResult, spearman
+from repro.tune.autotuner import Autotuner, ScoredCandidate, TuneResult
 from repro.tune.config import TunedConfig
 from repro.tune.cost import (
     CostEstimate,
@@ -39,5 +39,4 @@ __all__ = [
     "plan_slot_bytes",
     "predict_cost",
     "quantize_histogram",
-    "spearman",
 ]

@@ -1,20 +1,15 @@
-"""Two-stage autotuner: simulator-pruned search, measured calibration.
+"""Autotuner: simulator-scored search over plan configurations.
 
-Stage 1 scores every candidate ``(T, C, dense-threshold-ratio, ladder)``
+It scores every candidate ``(T, C, dense-threshold-ratio, ladder)``
 analytically — :func:`repro.tune.cost.predict_cost` (``run_scv_bucketed``
 cycles + slot-priced traffic + per-launch overhead) plus two kernel-body
 terms the plan-level model cannot see (chunk-step overhead/padding and the
 MXU/VPU crossover of the dense-tile split).  One simulator run per
 distinct tile is shared across every ladder at that tile, so the sweep is
-O(tiles) simulator passes, not O(candidates).
-
-Stage 2 builds real plans for the top-``k`` surviving ``(T, ladder)``
-pairs — the hand-picked default always rides along as a control — and
-times short measured aggregation runs; the measured winner becomes the
-:class:`TunedConfig`, cached in a :class:`TuneStore` keyed by quantized
-histogram signature x machine fingerprint (see ``signature.py`` for the
-staleness rule).  With ``calibrate=False`` the stage-1 winner is returned
-directly — the cheap mode the serve engine uses at admission time.
+O(tiles) simulator passes, not O(candidates).  The best-scored candidate
+becomes the :class:`TunedConfig` (``source="simulated"``), cached in a
+:class:`TuneStore` keyed by quantized histogram signature x machine
+fingerprint (see ``signature.py`` for the staleness rule).
 """
 from __future__ import annotations
 
@@ -28,8 +23,6 @@ from repro.core.formats import COOMatrix
 from repro.core.scv import (
     MXU_VPU_RATIO,
     bucket_caps_for,
-    coo_to_scv_tiles,
-    plan_from_tiles_bucketed,
     tile_nnz_histogram,
 )
 from repro.simul.dataflows import run_scv_bucketed
@@ -56,13 +49,11 @@ class ScoredCandidate:
     config: TunedConfig
     estimate: CostEstimate
     score_s: float  # estimate.seconds + chunk + dense terms
-    measured_s: Optional[float] = None
 
     def to_json(self) -> dict:
         return {
             "config": self.config.to_json(),
             "predicted_s": self.score_s,
-            "measured_s": self.measured_s,
             "estimate": self.estimate.to_json(),
         }
 
@@ -73,40 +64,14 @@ class TuneResult:
     config: TunedConfig
     cached: bool
     candidates: list = dataclasses.field(default_factory=list)
-    calibrated: list = dataclasses.field(default_factory=list)
-    rank_correlation: Optional[float] = None
     search_seconds: float = 0.0
-
-
-def spearman(xs, ys) -> float:
-    """Spearman rank correlation (average ranks on ties)."""
-    if len(xs) < 2:
-        return 1.0
-    rx = _ranks(xs)
-    ry = _ranks(ys)
-    sx, sy = np.std(rx), np.std(ry)
-    if sx == 0 or sy == 0:
-        return 0.0
-    return float(np.corrcoef(rx, ry)[0, 1])
-
-
-def _ranks(xs) -> np.ndarray:
-    xs = np.asarray(xs, dtype=np.float64)
-    order = np.argsort(xs, kind="stable")
-    ranks = np.empty(len(xs), dtype=np.float64)
-    ranks[order] = np.arange(len(xs), dtype=np.float64)
-    for v in np.unique(xs):
-        mask = xs == v
-        ranks[mask] = ranks[mask].mean()
-    return ranks
 
 
 def candidate_ladders(counts: np.ndarray, tile: int) -> tuple[tuple[int, ...], ...]:
     """Contiguous sub-ladders of the derived full ladder for ``tile``.
 
     ``bucket_caps_for`` gives the max-depth ladder; shallower contiguous
-    slices trade dummy/padding slots against launch count (PR 8's measured
-    A/B was exactly this family).  Chain-splitting at ``caps[-1]`` makes
+    slices trade dummy/padding slots against launch count.  Chain-splitting at ``caps[-1]`` makes
     every slice valid regardless of the heaviest tile.
     """
     full = bucket_caps_for(counts, tile)
@@ -129,25 +94,19 @@ class Autotuner:
         tiles: tuple[int, ...] = TILE_CANDIDATES,
         chunks: tuple[int, ...] = CHUNK_CANDIDATES,
         ratios: tuple[float, ...] = RATIO_CANDIDATES,
-        top_k: int = 3,
-        calibrate: bool = True,
-        calib_reps: int = 2,
     ):
         self.machine = machine if machine is not None else MachineConfig()
         self.store = store if store is not None else TuneStore()
         self.tiles = tuple(tiles)
         self.chunks = tuple(chunks)
         self.ratios = tuple(ratios)
-        self.top_k = int(top_k)
-        self.calibrate = bool(calibrate)
-        self.calib_reps = int(calib_reps)
         self.searches = 0
         self.cache_hits = 0
         self.last_result: Optional[TuneResult] = None
 
     # -- public entry ------------------------------------------------------
     def tune(self, adj: COOMatrix, n_features: int = 64) -> TunedConfig:
-        """Resolve the config for ``adj``: cache hit, or two-stage search."""
+        """Resolve the config for ``adj``: cache hit, or a search."""
         if adj.nnz == 0:
             return TunedConfig.default()
         counts_ref = tile_nnz_histogram(adj, TunedConfig.default().tile)
@@ -161,36 +120,27 @@ class Autotuner:
             return hit
         t0 = time.perf_counter()
         self.searches += 1
-        scored = self._stage1(adj, n_features)
-        result = TuneResult(key=key, config=scored[0].config, cached=False)
-        result.candidates = scored
-        if self.calibrate:
-            calibrated = self._stage2(adj, scored, n_features)
-            result.calibrated = calibrated
-            winner = min(calibrated, key=lambda c: c.measured_s)
-            result.rank_correlation = spearman(
-                [c.score_s for c in calibrated],
-                [c.measured_s for c in calibrated],
-            )
-            result.config = dataclasses.replace(winner.config, source="calibrated")
-        else:
-            result.config = dataclasses.replace(scored[0].config, source="simulated")
+        scored = self._score(adj, n_features)
+        result = TuneResult(
+            key=key,
+            config=dataclasses.replace(scored[0].config, source="simulated"),
+            cached=False,
+            candidates=scored,
+        )
         result.search_seconds = time.perf_counter() - t0
         self.store.put(
             key,
             result.config,
             meta={
                 "n_candidates": len(scored),
-                "n_calibrated": len(result.calibrated),
-                "rank_correlation": result.rank_correlation,
                 "search_seconds": result.search_seconds,
             },
         )
         self.last_result = result
         return result.config
 
-    # -- stage 1: analytic prune ------------------------------------------
-    def _stage1(self, adj: COOMatrix, n_features: int) -> list[ScoredCandidate]:
+    # -- analytic scoring ---------------------------------------------------
+    def _score(self, adj: COOMatrix, n_features: int) -> list[ScoredCandidate]:
         scored = []
         default = TunedConfig.default()
         for tile in self.tiles:
@@ -266,60 +216,6 @@ class Autotuner:
             self.ratios,
             key=lambda r: self._dense_term(counts, tile, r, n_features),
         )
-
-    # -- stage 2: measured calibration ------------------------------------
-    def _stage2(
-        self, adj: COOMatrix, scored: list[ScoredCandidate], n_features: int
-    ) -> list[ScoredCandidate]:
-        import jax
-        import jax.numpy as jnp
-
-        from repro.core.aggregate import aggregate_scv_plan
-
-        default = TunedConfig.default()
-        survivors: list[ScoredCandidate] = []
-        seen: set = set()
-        for cand in scored:
-            k = (cand.config.tile, cand.config.bucket_caps)
-            if k not in seen:
-                seen.add(k)
-                survivors.append(cand)
-            if len(survivors) >= self.top_k:
-                break
-        if (default.tile, default.bucket_caps) not in seen:
-            # the control: the hand-picked default is always measured too
-            ctl = next(
-                (
-                    c for c in scored
-                    if (c.config.tile, c.config.bucket_caps)
-                    == (default.tile, default.bucket_caps)
-                ),
-                ScoredCandidate(
-                    default,
-                    predict_cost(adj, default, n_features, machine=self.machine),
-                    float("inf"),
-                ),
-            )
-            survivors.append(ctl)
-
-        rng = np.random.default_rng(0)
-        z = jnp.asarray(
-            rng.integers(-3, 4, size=(adj.shape[1], n_features)).astype(np.float32)
-        )
-        agg = jax.jit(lambda p, zz: aggregate_scv_plan(p, zz, backend="jnp"))
-        out = []
-        for cand in survivors:
-            caps = cand.config.bucket_caps or (cand.config.cap,)
-            tiles = coo_to_scv_tiles(adj, cand.config.tile, cap=caps[-1])
-            plan = plan_from_tiles_bucketed(tiles, caps=caps)
-            agg(plan, z).block_until_ready()  # compile + warm
-            best = float("inf")
-            for _ in range(self.calib_reps):
-                t0 = time.perf_counter()
-                agg(plan, z).block_until_ready()
-                best = min(best, time.perf_counter() - t0)
-            out.append(dataclasses.replace(cand, measured_s=best))
-        return out
 
 
 def _segment_tile_counts(counts, caps) -> dict[int, int]:

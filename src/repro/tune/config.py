@@ -24,7 +24,7 @@ class TunedConfig:
 
     ``bucket_caps`` is the ascending capacity ladder; an empty tuple means
     single-cap plans at ``cap``.  ``source`` records how the config was
-    obtained (``default`` / ``simulated`` / ``calibrated`` / ``cache``) —
+    obtained (``default`` / ``simulated`` / ``cache``) —
     metadata only, excluded from equality so a cache round-trip compares
     equal to the freshly tuned config.
     """

@@ -1,15 +1,14 @@
-"""Stage-1 analytic cost model: simulator cycles + slot-priced traffic.
+"""The tuner's analytic cost model: simulator cycles + slot-priced traffic.
 
 One byte model for two callers.  :func:`plan_slot_bytes` prices the device
 plan triple (rows/cols/vals) at *launched* capacity slots — the number
 :func:`core.scv.launched_slots` computes from a histogram and
 ``core.exec.placement_bytes(n_slots=...)`` consumes for placement — so the
 autotuner and ``PlanExecutor.decide_sharding`` charge padding identically.
-:func:`predict_cost` is what stage 1 of the tuner ranks candidates by:
+:func:`predict_cost` is what the tuner ranks candidates by:
 ``simul.dataflows.run_scv_bucketed`` compute cycles plus DRAM-bandwidth
 time over the slot-priced traffic, plus a per-launch charge (one kernel
-launch per ladder segment — the term that penalizes deep ladders, the
-measured effect that flipped the PR 8 serving default to 2-deep).
+launch per ladder segment — the term that penalizes deep ladders).
 """
 from __future__ import annotations
 
@@ -27,9 +26,8 @@ from repro.tune.config import TunedConfig
 #: Modeled clock of the simulated vector processor (paper §V: 1 GHz-class).
 CLOCK_HZ = 1e9
 #: Per-kernel-launch overhead charged per ladder segment.  Dispatch is a
-#: host-side cost, so this is a fraction tuned to reproduce the PR 8
-#: serve_bench A/B ordering (2-deep beating 3-deep on the sparse pool)
-#: rather than a hardware constant.
+#: host-side cost, so this is an assumed figure, not a hardware constant;
+#: no chip measurement has set it.
 LAUNCH_OVERHEAD_S = 2e-3
 
 

@@ -1,9 +1,8 @@
 """On-disk cache of tuned configs, keyed by regime signature x machine.
 
-A flat JSON file (atomic tmp+rename writes) so concurrent benches and a
+A flat JSON file (atomic tmp+rename writes) so several tuners and a
 serving process can share one store; misses are cheap (one dict lookup
-after an O(nnz) histogram), hits skip both the simulator sweep and the
-measured calibration.  Staleness is structural: the key embeds
+after an O(nnz) histogram), hits skip the simulator sweep.  Staleness is structural: the key embeds
 :func:`repro.tune.signature.machine_fingerprint`, so a changed
 ``MachineConfig`` (or jax backend) never sees old entries.
 """

@@ -23,6 +23,7 @@ from repro.core import (
     load_imbalance,
     plan_from_tiles,
     plan_from_tiles_bucketed,
+    shard_plan,
     split_equal_nnz,
 )
 from repro.core.aggregate import aggregate, aggregate_scv_plan
@@ -71,6 +72,41 @@ def test_placement_bytes_model():
         "z_gather"
     ] / 2
     assert half["out"] == pb["out"]
+
+
+@pytest.fixture(scope="module")
+def sparse_bucketed():
+    """131k-node, 1M-edge power-law graph, bucketed at its own ladder."""
+    from repro.core.scv import bucket_caps_for, tile_nnz_histogram
+
+    adj = powerlaw_graph(1 << 17, 1_000_000, seed=0)
+    caps = bucket_caps_for(tile_nnz_histogram(adj, 64), 64)
+    plan = plan_from_tiles_bucketed(
+        coo_to_scv_tiles(adj, 64, cap=caps[-1]), caps=caps
+    )
+    assert len(plan.segments) > 1
+    return adj, plan
+
+
+@pytest.mark.parametrize("tp,fp", [(8, 1), (1, 8), (4, 2)])
+def test_placement_bytes_match_placed_plan(sparse_bucketed, tp, fp):
+    """The byte model's per-device resident bytes (launched slots priced,
+    ``n_slots``) stay within [0.95, 1.10] of a placed plan's real leaves:
+    the residual is only the integer tile-boundary split of the spans."""
+    from repro.core.exec import placement_bytes
+    from repro.tune import plan_launched_slots
+
+    adj, plan = sparse_bucketed
+    n, f = adj.shape[0], 64
+    stacked = shard_plan(plan, split_equal_nnz(plan, tp))
+    plan_bytes = sum(
+        s.rows.nbytes + s.cols.nbytes + s.vals.nbytes for s in stacked.segments
+    ) / tp
+    actual = plan_bytes + 2 * n * f * 4 / fp  # + Z slab + output slab
+    pred = placement_bytes(
+        adj.nnz, f, tp, fp, n_rows=n, n_slots=plan_launched_slots(plan)
+    )
+    assert 0.95 <= actual / pred["resident"] <= 1.10
 
 
 def test_decision_validation():

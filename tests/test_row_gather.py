@@ -139,6 +139,9 @@ def test_auto_body_follows_capacity_and_size(cap, tile, n_tiles, body):
     assert uses_row_gather(cap, tile, n_tiles) == (body == "rows")
     assert resolve_body("auto", cap, tile, n_tiles) == body
     assert resolve_body("vector", cap, tile, n_tiles) == "vector"
+    assert resolve_body("rows", cap, tile, n_tiles) == "rows"
+    with pytest.raises(ValueError, match="unknown kernel body"):
+        resolve_body("per_entry", cap, tile, n_tiles)
 
 
 def test_row_gather_launches_count_small_caps(rng):

@@ -2,7 +2,7 @@
 
 Covers the PR's acceptance criteria:
 
-* vectorized kernel body == scalar body == jnp oracle, bit for bit on
+* vectorized kernel body == jnp oracle, bit for bit on
   integer-valued inputs (order-independent sums) — including the
   in-kernel dense-tile branch,
 * kernel edge cases: zero-tile plans, all-dummy (coverage-only) tiles,
@@ -72,7 +72,7 @@ def _int_z(rng, n, f):
 
 
 # ---------------------------------------------------------------------------
-# vector body == scalar body == oracle, bit for bit
+# vector body == oracle, bit for bit
 # ---------------------------------------------------------------------------
 def test_vector_scalar_oracle_bit_identical(rng):
     a = _int_coo(rng, 96, 96, 0.06, dense_block=(0, 32, 32))
@@ -80,15 +80,9 @@ def test_vector_scalar_oracle_bit_identical(rng):
     tiles = coo_to_scv_tiles(coo_from_dense(a), 32, cap=1024)
     plan = plan_from_tiles(tiles, with_perm=False)
     assert int(np.asarray(plan.nnz_in_tile).max()) > dense_tile_threshold(32)
-    outs = {
-        body: np.asarray(
-            kops.scv_spmm_plan(plan, z, interpret=True, body=body)
-        )
-        for body in ("vector", "scalar")
-    }
+    out = np.asarray(kops.scv_spmm_plan(plan, z, interpret=True, body="vector"))
     ref = np.asarray(kref.scv_spmm_reference_plan(plan, z))
-    np.testing.assert_array_equal(outs["vector"], ref)
-    np.testing.assert_array_equal(outs["scalar"], ref)
+    np.testing.assert_array_equal(out, ref)
     np.testing.assert_array_equal(ref[:96], a @ np.asarray(z))
 
 

@@ -127,7 +127,7 @@ def test_scv002_tunable_constants_in_repro_scope():
 def test_scv002_tunable_constants_scoped_and_owned():
     src = "tile = 64\nbucket_caps = (8, 32)\n"
     # benchmarks/tests sweep candidate values by design — out of scope
-    assert _rules(src, "benchmarks/serve_bench.py") == []
+    assert _rules(src, "benchmarks/figures.py") == []
     assert _rules(src, "tests/test_foo.py") == []
     # TunedConfig is the other sanctioned owner
     assert _rules(src, "src/repro/tune/config.py") == []
@@ -238,7 +238,7 @@ def test_scv006_scoped_to_stream_package():
     )
     # rebuilds are the whole point everywhere else
     assert _rules(src, "src/repro/serve/graph_engine.py") == []
-    assert _rules(src, "benchmarks/stream_bench.py") == []
+    assert _rules(src, "benchmarks/run.py") == []
     assert _rules(src, "tests/test_stream.py") == []
 
 

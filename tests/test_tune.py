@@ -1,6 +1,6 @@
 """Autotuner subsystem (repro.tune): TunedConfig, histogram/ladder edge
 cases, signature stability, slot-priced byte model, store round-trips,
-two-stage search caching, and the serve engine's autotune integration."""
+search caching, and the serve engine's autotune integration."""
 import dataclasses
 
 import jax
@@ -34,7 +34,6 @@ from repro.tune import (
     machine_fingerprint,
     plan_launched_slots,
     quantize_histogram,
-    spearman,
 )
 
 
@@ -152,7 +151,7 @@ def test_tuned_config_defaults_mirror_core_constants():
 
 def test_tuned_config_equality_ignores_source():
     a = TunedConfig(source="default")
-    b = dataclasses.replace(a, source="calibrated")
+    b = dataclasses.replace(a, source="simulated")
     assert a == b and hash(a) == hash(b)
     assert a != dataclasses.replace(a, tile=128)
 
@@ -211,7 +210,7 @@ def test_store_roundtrip_on_disk(tmp_path):
     path = tmp_path / "tune.json"
     s1 = TuneStore(path)
     assert s1.get("k") is None
-    cfg = TunedConfig(tile=128, bucket_caps=(16, 64), source="calibrated")
+    cfg = TunedConfig(tile=128, bucket_caps=(16, 64), source="simulated")
     s1.put("k", cfg, meta={"note": 1})
     s2 = TuneStore(path)  # fresh process view
     got = s2.get("k")
@@ -232,7 +231,7 @@ def test_store_corrupt_file_is_empty(tmp_path):
 def test_autotuner_search_then_cache_hit(tmp_path):
     adj = powerlaw_graph(1 << 12, 60_000, seed=0)
     store = TuneStore(tmp_path / "tune.json")
-    tuner = Autotuner(store=store, calibrate=False)
+    tuner = Autotuner(store=store)
     cfg = tuner.tune(adj, n_features=16)
     assert tuner.searches == 1 and tuner.cache_hits == 0
     assert cfg.source == "simulated"
@@ -242,7 +241,7 @@ def test_autotuner_search_then_cache_hit(tmp_path):
     assert tuner.searches == 1 and tuner.cache_hits == 1
     assert tuner.last_result.cached
     # a fresh tuner sharing the on-disk store inherits the hit
-    t2 = Autotuner(store=TuneStore(tmp_path / "tune.json"), calibrate=False)
+    t2 = Autotuner(store=TuneStore(tmp_path / "tune.json"))
     assert t2.tune(adj, n_features=16) == cfg
     assert t2.searches == 0 and t2.cache_hits == 1
 
@@ -250,41 +249,17 @@ def test_autotuner_search_then_cache_hit(tmp_path):
 def test_autotuner_machine_change_is_stale(tmp_path):
     adj = powerlaw_graph(1 << 12, 60_000, seed=0)
     store = TuneStore(tmp_path / "tune.json")
-    Autotuner(store=store, calibrate=False).tune(adj, n_features=16)
-    other = Autotuner(
-        machine=MachineConfig(dram_gbps=4.0), store=store, calibrate=False
-    )
+    Autotuner(store=store).tune(adj, n_features=16)
+    other = Autotuner(machine=MachineConfig(dram_gbps=4.0), store=store)
     other.tune(adj, n_features=16)
     assert other.searches == 1  # fingerprint miss -> fresh search
     assert len(store) == 2
 
 
-def test_autotuner_calibration_includes_default_control():
-    adj = powerlaw_graph(1 << 12, 60_000, seed=0)
-    tuner = Autotuner(top_k=2, calib_reps=1)
-    cfg = tuner.tune(adj, n_features=16)
-    res = tuner.last_result
-    assert cfg.source == "calibrated"
-    measured = {(c.config.tile, c.config.bucket_caps) for c in res.calibrated}
-    assert (DEFAULT_TILE, DEFAULT_LADDER) in measured
-    # winner is measured-best, so it can never lose to the default
-    best = min(res.calibrated, key=lambda c: c.measured_s)
-    assert (cfg.tile, cfg.bucket_caps) == (
-        best.config.tile, best.config.bucket_caps
-    )
-    assert res.rank_correlation is not None
-
-
 def test_autotuner_empty_graph_returns_default():
-    tuner = Autotuner(calibrate=False)
+    tuner = Autotuner()
     assert tuner.tune(_empty_coo()) == TunedConfig.default()
     assert tuner.searches == 0
-
-
-def test_spearman():
-    assert spearman([1, 2, 3], [10, 20, 30]) == pytest.approx(1.0)
-    assert spearman([1, 2, 3], [30, 20, 10]) == pytest.approx(-1.0)
-    assert spearman([1.0], [2.0]) == 1.0
 
 
 # ---------------------------------------------------------------------------

@@ -173,8 +173,8 @@ def _collect_traced_functions(tree: ast.Module, rel: str = "") -> set[str]:
                     )
         if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
             # The `_kernel*` naming idiom marks Pallas bodies, but only
-            # inside the kernels/ tree (benchmarks reuse the prefix for
-            # host-side drivers).
+            # inside the kernels/ tree (host-side code elsewhere may use
+            # the prefix).
             if node.name.startswith("_kernel") and "kernels/" in rel:
                 traced.add(node.name)
             for dec in node.decorator_list:
@@ -300,7 +300,7 @@ class FileChecker:
             return  # the owners of the constants
         # The tunable plan constants (tile / cap / ladder) are policed
         # inside src/repro/ only: benchmarks and tests sweep candidate
-        # values by design (serve_bench ladder A/B, kernel_bench TILE).
+        # values by design.
         tunable_scope = "src/repro/" in rel or rel.startswith("repro/")
         for node in ast.walk(tree):
             # 1/16 or 1.0/16.0 → MXU_VPU_RATIO
